@@ -384,6 +384,10 @@ def main() -> None:
                          "baseline (--json PATH, default BENCH_tpch.json)")
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+
     if args.check:
         sys.exit(run_check(args.sf, args.json or "BENCH_tpch.json"))
 

@@ -138,7 +138,7 @@ def hash_state(lo: jnp.ndarray, hi: jnp.ndarray) -> Tuple[jnp.ndarray,
                                                           jnp.ndarray,
                                                           jnp.ndarray]:
     """(h, g1, g2) device hash state from uint32 key halves — computed
-    once per key column and reused by every `probe_hashed_dev` call
+    once per key column and reused by every `probe_rows` call
     (the device analogue of the host engine's lazy hash cache)."""
     h = hashing.hash64(lo, hi)
     g1 = hashing.fmix32(h ^ hashing.GOLDEN)
@@ -146,19 +146,19 @@ def hash_state(lo: jnp.ndarray, hi: jnp.ndarray) -> Tuple[jnp.ndarray,
     return h, g1, g2
 
 
-@functools.partial(jax.jit, static_argnames=("k",))
-def probe_hashed_dev(words: jnp.ndarray, h: jnp.ndarray, g1: jnp.ndarray,
-                     g2: jnp.ndarray, k: int = DEFAULT_K) -> jnp.ndarray:
-    """`probe` from pre-hashed state: k flat word gathers instead of an
-    8-lane block row gather + take_along_axis, and no rehash per filter.
-    Bit-identical to `probe` over the same keys."""
-    nblocks = words.shape[0]
-    flat = words.reshape(-1)
-    base = _block_index(h, nblocks).astype(jnp.int32) * LANES
+def probe_rows(words, h, g1, g2, k: int) -> jnp.ndarray:
+    """`probe` from pre-hashed state (`hash_state`; no rehash per
+    filter), bit-identical to it: one block-row gather per key, then
+    the k in-block words. (A flat word gather would need the
+    (nblocks, 8) -> flat relayout, which XLA's TPU compiler takes tens
+    of seconds over at 2^15-2^17 blocks.)"""
+    rows = words[_block_index(h, words.shape[0]).astype(jnp.int32)]
     out = jnp.ones(h.shape, jnp.bool_)
     for j in range(k):
         pos = (g1 + jnp.uint32(j) * g2) & jnp.uint32(BLOCK_BITS - 1)
-        w = flat[base + (pos >> jnp.uint32(5)).astype(jnp.int32)]
+        w = jnp.take_along_axis(
+            rows, (pos >> jnp.uint32(5)).astype(jnp.int32)[:, None],
+            axis=1)[:, 0]
         out &= ((w >> (pos & jnp.uint32(31))) & jnp.uint32(1)) == 1
     return out
 
@@ -427,6 +427,45 @@ def _bucket(n: int, floor: int = 64) -> int:
     caches at O(log n) entries. Canonical copy — the engine layer and
     the distributed shard helpers reuse it."""
     return max(floor, int(2 ** np.ceil(np.log2(max(n, 1)))))
+
+
+def prefix_sum(x: jnp.ndarray, bits: int = 31) -> jnp.ndarray:
+    """Inclusive int32 running sum of a non-negative 1-D array whose
+    values fit in `bits` bits.
+
+    Not `jnp.cumsum`: XLA's TPU compiler takes 8-35 s per shape for a
+    cumsum at 2^18-2^21 elements. Here each 128-element segment is
+    prefix-summed on the MXU — an exact int8 x int8 -> int32 product
+    with an upper-triangular ones matrix, one product per 7-bit digit
+    of the values — and only the segment totals (1/128 of the length)
+    go through `jnp.cumsum`. Compiles in 1-3 s at 2^16-2^23."""
+    n = x.shape[0]
+    x = x.astype(jnp.int32)
+    if n % 128:
+        x = jnp.pad(x, (0, 128 - n % 128))
+    seg = x.reshape(-1, 128)
+    lanes = jnp.arange(128)
+    tri = (lanes[:, None] <= lanes[None, :]).astype(jnp.int8)
+    inner = jnp.zeros(seg.shape, jnp.int32)
+    for d in range(0, bits, 7):
+        digit = ((seg >> d) & 127).astype(jnp.int8)
+        inner = inner + (jnp.dot(digit, tri,
+                                 preferred_element_type=jnp.int32) << d)
+    tot = inner[:, -1]
+    run = inner + (jnp.cumsum(tot, dtype=jnp.int32) - tot)[:, None]
+    return run.reshape(-1)[:n]
+
+
+def flatnonzero(ok: jnp.ndarray, size: int) -> jnp.ndarray:
+    """`jnp.nonzero(ok, size=size, fill_value=0)[0]` as int32, without a
+    scatter: the j-th set position is the first index whose running
+    count reaches j + 1 (a binary search over `prefix_sum`). XLA's TPU
+    compiler spends 15-40 s per shape on the scatter form at 2^17-2^23
+    rows."""
+    run = prefix_sum(ok, bits=1)
+    idx = jnp.searchsorted(run, jnp.arange(1, size + 1, dtype=jnp.int32),
+                           side="left", method="scan")
+    return jnp.where(idx < ok.shape[0], idx, 0).astype(jnp.int32)
 
 
 def _pad(a: np.ndarray, n: int, fill=0) -> np.ndarray:

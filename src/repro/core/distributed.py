@@ -31,7 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-import repro.launch.mesh  # noqa: F401  (installs jax.shard_map compat)
 from repro.core import bloom, hashing
 
 

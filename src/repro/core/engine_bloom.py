@@ -23,7 +23,7 @@ graph; this module decides *how* each vertex's filter work is executed:
 * **compacted device scans** — the device backends keep a re-bucketed
   survivor-id array between probes (later filters probe ~survivors,
   not the padded column), hash each column on device once
-  (`bloom.hash_state` + `probe_hashed_dev`), and off-TPU route builds
+  (`bloom.hash_state` + `probe_rows`), and off-TPU route builds
   through the bit-identical host mirror and compaction through host
   flatnonzero (XLA:CPU serializes the build scatter and scans for
   sized-nonzero; DESIGN.md §7);
@@ -38,7 +38,7 @@ test_engine_bloom.py` asserts word-level equality against the
 
 * ``numpy``  — host mirror; the CPU wall-clock path (DESIGN.md §7);
 * ``jax``    — jit'd `repro.core.bloom` ops; the distributed path;
-* ``pallas`` — `repro.kernels.bloom` TPU kernels (interpret mode off-TPU).
+* ``pallas`` — `repro.kernels.bloom` TPU kernels (interpret mode on CPU).
 """
 from __future__ import annotations
 
@@ -319,13 +319,13 @@ def build_alive_np(ek: EngineKeys, alive: Optional[np.ndarray],
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def _probe_hashed_count(words, h, g1, g2, count, k):
-    ok = bloom.probe_hashed_dev(words, h, g1, g2, k=k)
+    ok = bloom.probe_rows(words, h, g1, g2, k)
     return ok & (jnp.arange(ok.shape[0]) < count)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def _probe_hashed_gather(words, h, g1, g2, idx, count, k):
-    ok = bloom.probe_hashed_dev(words, h[idx], g1[idx], g2[idx], k=k)
+    ok = bloom.probe_rows(words, h[idx], g1[idx], g2[idx], k)
     return ok & (jnp.arange(idx.shape[0]) < count)
 
 
@@ -370,12 +370,12 @@ def _iota_mask(size, count):
 
 @functools.partial(jax.jit, static_argnames=("size",))
 def _nonzero_idx(ok, size):
-    return jnp.nonzero(ok, size=size, fill_value=0)[0].astype(jnp.int32)
+    return bloom.flatnonzero(ok, size)
 
 
 @functools.partial(jax.jit, static_argnames=("size",))
 def _nonzero_gather(ok, idx, size):
-    return idx[jnp.nonzero(ok, size=size, fill_value=0)[0]]
+    return idx[bloom.flatnonzero(ok, size)]
 
 
 def _compact(ok, idx, bucket: int):
@@ -398,27 +398,12 @@ _U32MAX = np.uint32(0xFFFFFFFF)
 
 
 def _fused_and(words, hs, g1s, g2s, ok, k):
-    """Traced fused-probe core: AND every packed filter into `ok`,
-    appending the live count after each filter. Same hash rounds and
-    flat word layout as `probe_packed_np` — bit-identical survivors."""
-    flat = jnp.concatenate([w.reshape(-1) for w in words])
-    off = 0
+    """Traced fused-probe core: AND every incoming filter into `ok`,
+    appending the live count after each filter. Same hash rounds as
+    `probe_packed_np` — bit-identical survivors."""
     counts = []
     for f, w in enumerate(words):
-        nb = w.shape[0]
-        l2 = int(np.log2(nb))
-        h, g1, g2 = hs[f], g1s[f], g2s[f]
-        if l2:
-            base = ((h >> jnp.uint32(32 - l2)).astype(jnp.int32)
-                    + np.int32(off)) * np.int32(LANES)
-        else:
-            base = jnp.full(h.shape[0], off * LANES, jnp.int32)
-        for j in range(k):
-            pos = (g1 + jnp.uint32(j) * g2) & jnp.uint32(BLOCK_BITS - 1)
-            w32 = flat[base + (pos >> jnp.uint32(5)).astype(jnp.int32)]
-            ok = ok & (((w32 >> (pos & jnp.uint32(31))) & jnp.uint32(1))
-                       == jnp.uint32(1))
-        off += nb
+        ok = ok & bloom.probe_rows(w, hs[f], g1s[f], g2s[f], k)
         counts.append(jnp.sum(ok, dtype=jnp.int32))
     return ok, jnp.stack(counts)
 
@@ -428,8 +413,7 @@ def _fused_probe_count(words, hs, g1s, g2s, count, k):
     n = hs[0].shape[0]
     ok = jnp.arange(n, dtype=jnp.int32) < count
     ok, counts = _fused_and(words, hs, g1s, g2s, ok, k)
-    idx = jnp.nonzero(ok, size=n, fill_value=0)[0].astype(jnp.int32)
-    return idx, counts
+    return bloom.flatnonzero(ok, n), counts
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -440,8 +424,7 @@ def _fused_probe_gather(words, hs, g1s, g2s, idx, count, k):
     g1g = tuple(g[idx] for g in g1s)
     g2g = tuple(g[idx] for g in g2s)
     ok, counts = _fused_and(words, hg, g1g, g2g, ok, k)
-    new_idx = idx[jnp.nonzero(ok, size=n, fill_value=0)[0]]
-    return new_idx, counts
+    return idx[bloom.flatnonzero(ok, n)], counts
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -451,8 +434,7 @@ def _fused_pallas_count(words, los, his, count, k, interpret):
     n = los[0].shape[0]
     cum = cum & (jnp.arange(n, dtype=jnp.int32) < count)[None, :]
     counts = jnp.sum(cum, axis=1, dtype=jnp.int32)
-    idx = jnp.nonzero(cum[-1], size=n, fill_value=0)[0].astype(jnp.int32)
-    return idx, counts
+    return bloom.flatnonzero(cum[-1], n), counts
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -464,8 +446,7 @@ def _fused_pallas_gather(words, los, his, idx, count, k, interpret):
     n = idx.shape[0]
     cum = cum & (jnp.arange(n, dtype=jnp.int32) < count)[None, :]
     counts = jnp.sum(cum, axis=1, dtype=jnp.int32)
-    new_idx = idx[jnp.nonzero(cum[-1], size=n, fill_value=0)[0]]
-    return new_idx, counts
+    return idx[bloom.flatnonzero(cum[-1], n)], counts
 
 
 def _bound_halves(v) -> Tuple[np.uint32, np.uint32, np.uint32]:
@@ -497,8 +478,7 @@ def _range_cut_count(lo_col, hi_col, count, blo_lo, blo_hi, bhi_lo,
     n = lo_col.shape[0]
     ok = (_range_keep(lo_col, hi_col, blo_lo, blo_hi, bhi_lo, bhi_hi)
           & (jnp.arange(n, dtype=jnp.int32) < count))
-    idx = jnp.nonzero(ok, size=n, fill_value=0)[0].astype(jnp.int32)
-    return idx, jnp.sum(ok, dtype=jnp.int32)
+    return bloom.flatnonzero(ok, n), jnp.sum(ok, dtype=jnp.int32)
 
 
 @jax.jit
@@ -508,8 +488,7 @@ def _range_cut_gather(lo_col, hi_col, idx, count, blo_lo, blo_hi,
     ok = (_range_keep(lo_col[idx], hi_col[idx], blo_lo, blo_hi, bhi_lo,
                       bhi_hi)
           & (jnp.arange(n, dtype=jnp.int32) < count))
-    new_idx = idx[jnp.nonzero(ok, size=n, fill_value=0)[0]]
-    return new_idx, jnp.sum(ok, dtype=jnp.int32)
+    return idx[bloom.flatnonzero(ok, n)], jnp.sum(ok, dtype=jnp.int32)
 
 
 def _minmax_live(lo_col, hi_col, live):
@@ -739,11 +718,12 @@ class _DeviceScan(VertexScan):
     (clipped to row 0, masked by an iota compare — no separate validity
     array to maintain).
 
-    Builds read the survivor ids; off-TPU the jax engine routes them
-    through the bit-identical host mirror (`build_alive_np`), because
+    Builds read the survivor ids; the jax engine routes them through
+    the bit-identical host mirror (`build_alive_np`) — off-TPU because
     XLA:CPU serializes the build's scatter (~1 µs/row — measured 30x
-    slower than the host mirror); on TPU the device build kernel runs
-    from the same compacted ids."""
+    slower than the host mirror), on TPU because XLA's compiler takes
+    12-20 s per shape over it; the pallas engine's build kernel runs on
+    device from the same compacted ids."""
 
     def __init__(self, mask: np.ndarray, engine: "BloomEngine"):
         self._e = engine
@@ -1005,8 +985,9 @@ class BloomEngine:
       benches, tests)."""
 
     backend = "base"
-    #: device engines set True off-TPU: filter builds run through the
-    #: bit-identical host mirror (XLA:CPU serializes the build scatter)
+    #: filter builds run through the bit-identical host mirror (set by
+    #: the jax engine: XLA's build scatter is serialized on CPU and slow
+    #: to compile on TPU)
     host_build = False
     #: device engines set True off-TPU: survivor compaction runs on host
     #: (XLA:CPU's sized-nonzero is scan-heavy; the mask is synced for the
@@ -1123,8 +1104,9 @@ class NumpyEngine(BloomEngine):
 class JaxEngine(BloomEngine):
     """jit'd `repro.core.bloom` ops over bucketed, survivor-compacted
     batches: device hash state per column is computed once
-    (`EngineKeys.dev_hashed`), every probe is the hashed flat-gather op,
-    and off-TPU builds run through the host mirror."""
+    (`EngineKeys.dev_hashed`), every probe is the hashed block-row
+    gather, and builds run through the host mirror (on TPU, and
+    off-TPU outside device-resident mode)."""
 
     backend = "jax"
 
@@ -1135,12 +1117,15 @@ class JaxEngine(BloomEngine):
         if device_resident is None:
             device_resident = on_tpu
         self.device_resident = bool(device_resident)
-        # device-resident mode keeps builds and compaction on device even
-        # off-TPU (the pallas-interpret/CI validation posture); otherwise
-        # off-TPU routes both through the bit-identical host mirrors
-        host_side = not on_tpu and not self.device_resident
-        self.host_build = host_side
-        self.host_compact = host_side
+        # device-resident mode keeps compaction on device even off-TPU
+        # (the CI validation posture); otherwise off-TPU routes it
+        # through host flatnonzero. Builds run through the bit-identical
+        # host mirror off-TPU (unless device-resident) and always on
+        # TPU, where XLA's compiler takes 12-20 s per (keys, blocks)
+        # shape over the build scatter — the pallas engine's build
+        # kernel is the device build there
+        self.host_compact = not on_tpu and not self.device_resident
+        self.host_build = on_tpu or self.host_compact
 
     def keys(self, values):
         lo, hi = hashing.key_halves(np.asarray(values))
@@ -1180,7 +1165,8 @@ class JaxEngine(BloomEngine):
 
 
 class PallasEngine(BloomEngine):
-    """`repro.kernels.bloom` TPU kernels; interpret mode off-TPU.
+    """`repro.kernels.bloom` TPU kernels (interpret mode on the cpu
+    platform, `repro.kernels.resolve_interpret`).
     Buckets are TILE-aligned (the kernels' grid contract)."""
 
     backend = "pallas"
@@ -1189,10 +1175,9 @@ class PallasEngine(BloomEngine):
                  interpret: Optional[bool] = None,
                  device_resident: Optional[bool] = None):
         super().__init__(k)
+        from repro.kernels import resolve_interpret
         on_tpu = jax.default_backend() == "tpu"
-        if interpret is None:
-            interpret = not on_tpu
-        self.interpret = bool(interpret)
+        self.interpret = resolve_interpret(interpret)
         if device_resident is None:
             device_resident = on_tpu
         self.device_resident = bool(device_resident)

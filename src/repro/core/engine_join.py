@@ -31,7 +31,7 @@ phase itself stop re-materializing them. Two layers:
     duplicate-free (the dimension-table case; detected from the map's
     occupancy, which dedups equal keys), host fallback otherwise;
   - ``pallas`` — the TPU kernels in `repro.kernels.semijoin` (interpret
-    mode off-TPU), same unique-build contract.
+    mode on CPU), same unique-build contract.
 
 The output contract — probe rows in original order; a probe row's
 matches in the build side's stable key order — makes every downstream
@@ -306,9 +306,11 @@ class _HashMapJoinEngine(JoinEngine):
     reference. Duplicates are detected from the map occupancy (equal
     keys dedup into one slot) and fall back to the host engine."""
 
-    #: builds above this size fall back to host (the serial-insert build
-    #: is only worth jit/kernel dispatch below it off-TPU)
-    device_max_build = 1 << 22
+    #: builds above this size run on host: the serial-insert build is
+    #: only worth jit/kernel dispatch below it off-TPU, and its four
+    #: tables (capacity 2x the next power of two) stay within the
+    #: kernel's VMEM budget on TPU
+    device_max_build = 1 << 21
 
     #: device-resident data plane (DESIGN.md §15): route every join
     #: through the sorted-segment device path
@@ -397,7 +399,7 @@ class JaxJoinEngine(_HashMapJoinEngine):
 
 
 class PallasJoinEngine(_HashMapJoinEngine):
-    """TPU kernels; interpret mode off-TPU. The serialized build loop is
+    """TPU kernels; interpret mode on CPU. The serialized build loop is
     prohibitive under the interpreter, so off-TPU builds route through
     the jit'd jnp builder (insert order is identical, so the table
     layout — and therefore every lookup — is bit-identical) while
@@ -410,11 +412,11 @@ class PallasJoinEngine(_HashMapJoinEngine):
     def __init__(self, interpret: Optional[bool] = None,
                  device_resident: Optional[bool] = None):
         import jax
+        from repro.kernels import resolve_interpret
         on_tpu = jax.default_backend() == "tpu"
         super().__init__(device_resident=on_tpu if device_resident is None
                          else device_resident)
-        self.interpret = bool(not on_tpu if interpret is None
-                              else interpret)
+        self.interpret = resolve_interpret(interpret)
 
     def _build(self, build_key):
         from repro.kernels.semijoin import ops as sj

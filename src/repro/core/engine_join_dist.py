@@ -61,6 +61,7 @@ retries/replays/hedges re-run pure functions of host-resident inputs.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -152,6 +153,8 @@ class SimulatedExchange:
     process has a single XLA device (the default test session)."""
 
     device_backed = False
+    #: devices the shards live on (None: host-simulated shards)
+    devices = None
 
     def __init__(self, nshards: int):
         if nshards < 1 or nshards & (nshards - 1):
@@ -178,10 +181,9 @@ class SimulatedExchange:
 
 
 class MeshExchange:
-    """Real collectives over a 1-D `data` mesh inside `jax.shard_map`
-    (via the `launch/mesh.py` compat shims, so old and new jax spell it
-    identically). Blocks pad to a shared power-of-two bucket so each
-    (nshards, bucket, width) shape jit-compiles once."""
+    """Real collectives over a 1-D `data` mesh inside `jax.shard_map`.
+    Blocks pad to a shared power-of-two bucket so each (nshards, bucket,
+    width) shape jit-compiles once."""
 
     device_backed = True
 
@@ -214,6 +216,9 @@ class MeshExchange:
             ag, mesh=mesh, in_specs=spec, out_specs=spec))
         self._sharding = NamedSharding(mesh, spec)
         self._p = p
+        self.devices = list(mesh.devices.flat)
+        #: ids of the devices shard blocks were placed on
+        self.placement: set = set()
 
     def _bucket(self, n: int) -> int:
         from repro.core.bloom import _bucket
@@ -224,7 +229,9 @@ class MeshExchange:
 
         from repro.core import device_plane
         device_plane.count_h2d(arr.nbytes)
-        return jax.device_put(arr, self._sharding)
+        out = jax.device_put(arr, self._sharding)
+        self.placement.update(d.id for d in out.devices())
+        return out
 
     def all_to_all(self, blocks: List[List[np.ndarray]]) -> List[np.ndarray]:
         faultinject.fire("exchange.send")
@@ -399,11 +406,14 @@ def broadcast_join_indices(build_key: np.ndarray, probe_key: np.ndarray,
                            how: str, exchange, engine: JoinEngine,
                            build_valid: Optional[np.ndarray] = None,
                            probe_valid: Optional[np.ndarray] = None,
-                           recover: Optional[ExchangeRecovery] = None
+                           recover: Optional[ExchangeRecovery] = None,
+                           placement: Optional[set] = None
                            ) -> Tuple[np.ndarray, np.ndarray, int]:
     """All-gather the build keys; each shard joins its contiguous probe
     range against the full build side. Returns (build_idx, probe_idx,
-    wire_bytes).
+    wire_bytes). Over a device-backed exchange each shard's local join
+    runs on its own device; `placement` collects the ids of the devices
+    the local joins' outputs landed on.
 
     A nullable build side ships its validity plane alongside the key
     halves (gathered NULL build rows must not match anywhere); probe
@@ -423,22 +433,36 @@ def broadcast_join_indices(build_key: np.ndarray, probe_key: np.ndarray,
 
     def _shard_join(s):
         def run():
-            return engine.join_indices_valid(
-                full, probe_key[pb[s]:pb[s + 1]], how=how,
-                build_valid=full_valid,
-                probe_valid=None if probe_valid is None
-                else probe_valid[pb[s]:pb[s + 1]])
+            with _on_device(exchange.devices, s):
+                return engine.join_indices_valid(
+                    full, probe_key[pb[s]:pb[s + 1]], how=how,
+                    build_valid=full_valid,
+                    probe_valid=None if probe_valid is None
+                    else probe_valid[pb[s]:pb[s + 1]])
         return run
 
     bidx, pidx = [], []
     for s, (gb, gp) in enumerate(_run_shard_tasks(
             [_shard_join(s) for s in range(p)], recover, "broadcast")):
+        if placement is not None:
+            for out in (gb, gp):
+                if hasattr(out, "devices"):
+                    placement.update(d.id for d in out.devices())
         bidx.append(gb)
         pidx.append(gp + pb[s])
     row_bytes = KEY_WIRE_BYTES + (VALID_WIRE_BYTES
                                   if build_valid is not None else 0)
     wire = (p - 1) * len(build_key) * row_bytes
     return np.concatenate(bidx), np.concatenate(pidx), wire
+
+
+def _on_device(devices, s: int):
+    """Default-device scope for shard `s`'s local join (no-op for
+    host-simulated shards)."""
+    if devices is None:
+        return contextlib.nullcontext()
+    import jax
+    return jax.default_device(devices[s])
 
 
 def shuffle_join_indices(build_key: np.ndarray, probe_key: np.ndarray,
@@ -531,6 +555,9 @@ class DistStats:
     nshards: int
     device_backed: bool
     joins: List[DistJoinStat] = dataclasses.field(default_factory=list)
+    #: ids of the devices per-shard local joins ran on (device-backed
+    #: exchange with a device join engine)
+    local_devices: set = dataclasses.field(default_factory=set)
     #: recovery events (retry / retry_exhausted / replay / hedge dicts)
     #: appended by `ExchangeRecovery`; surfaced via ExecStats.report()
     recoveries: List[dict] = dataclasses.field(default_factory=list)
@@ -580,7 +607,8 @@ class DistributedJoinEngine(JoinEngine):
             # actually fits the device mesh (a power of two no larger
             # than the device count); otherwise simulate — an explicit
             # dist_shards must not crash on a smaller machine
-            dc = _device_count()
+            import jax
+            dc = jax.device_count()
             fits = nshards is None or (nshards <= dc
                                        and nshards & (nshards - 1) == 0)
             device = mesh is not None or (dc > 1 and fits)
@@ -659,7 +687,7 @@ class DistributedJoinEngine(JoinEngine):
                 rec, "broadcast", lambda: broadcast_join_indices(
                     build_key, probe_key, how, self.exchange, self.local,
                     build_valid=build_valid, probe_valid=probe_valid,
-                    recover=rec))
+                    recover=rec, placement=self.stats.local_devices))
             self.stats.joins.append(
                 DistJoinStat(how, "broadcast", nb, npr, 0, wire))
         else:
@@ -715,10 +743,3 @@ def get_distributed_engine(nshards: Optional[int] = None,
             _BASE_ENGINES[key] = base
     return base.fork()
 
-
-def _device_count() -> int:
-    try:
-        import jax
-        return jax.device_count()
-    except Exception:           # jax unavailable/uninitializable: simulate
-        return 1

@@ -1,36 +1,58 @@
-"""Pallas TPU kernels: blocked-Bloom build / probe / fused transfer.
+"""Pallas TPU kernels: blocked-Bloom build / probe / transfer.
 
 TPU adaptation (DESIGN.md §3): the filter is an array of 256-bit blocks
-(8 × uint32 lanes — one VMEM word row). One hash selects the block; k bit
-positions are derived by double hashing *within* the block, so a probe
-touches exactly one block row (single dynamic fetch + VPU bit math) and an
-insert read-modify-writes one block row.
+(8 × uint32 lanes). One hash selects the block; k bit positions are
+derived by double hashing *within* the block, so a probe reads k words of
+one block and an insert read-modify-writes one block.
 
-Tiling: keys stream through VMEM in (1, TILE) blocks over a 1-D grid; the
-filter itself is small (KBs–MBs) and is kept resident in VMEM for all grid
-steps (constant index_map). The build/transfer kernels exploit the
-sequential TPU grid to accumulate inserts into that resident block across
-steps — the canonical Pallas accumulator pattern.
+Layout, as the v5e compiler accepts it:
 
-The probe path is fully vectorized. The insert path is a serialized
-read-modify-write loop over the tile (scatter-OR has no vector primitive
-on the VPU); DESIGN.md discusses the MXU one-hot alternative for small
-filters. All kernels are bit-exact against the ref.py oracle.
+* **keys stream lane-dense.** A key column of n rows (n % TILE == 0) is
+  viewed as (n/128, 128) and tiled in (rows, 128) blocks, rows a
+  multiple of 8, over a 1-D grid.
+* **probe: filter in HBM, block rows fetched by XLA.** The vector unit
+  has no gather from VMEM, and an (nblocks, 8) filter kept in VMEM
+  would pad its 8 lanes to 128 (16× its size). So an XLA gather ahead
+  of the kernel fetches each key's block row from the HBM filter as 8
+  lane-dense word planes (`_probe_rows`; a row gather, because the TPU
+  compiler spends tens of seconds on the (nblocks, 8) -> flat relayout
+  a word gather needs at 2^15-2^17 blocks), and the kernel
+  hashes, picks the k probed words, tests their bits, and ANDs across
+  every filter of a vertex (the cumulative survivor mask after each
+  filter).
+* **build: filter resident in VMEM, lane-dense.** The filter accumulates
+  in its (nblocks/16, 128) view — 16 blocks per 128-lane row, no
+  padding — for all grid steps. Each key's block index and packed bit
+  positions arrive in SMEM tiles, and a serialized scalar loop
+  read-modify-writes one (1, 128) row per key (scatter-OR has no vector
+  primitive on the VPU). Filters above `VMEM_FILTER_MAX` are refused.
+
+All kernels are bit-exact against the ref.py oracle.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.bloom import BLOCK_BITS, LANES, DEFAULT_K
+from repro.core import hashing
+from repro.core.bloom import (
+    BLOCK_BITS, DEFAULT_K, LANES, _block_index, _positions,
+)
 from repro.core.hashing import GOLDEN
+from repro.kernels import resolve_interpret
 
-TILE = 1024  # keys per grid step
+TILE = 1024  # keys per build grid step; key counts are multiples of TILE
+_LANE = 128
+_BLOCKS_PER_ROW = _LANE // LANES      # 16 filter blocks per 128-lane row
+_MAX_ROWS = 64                        # probe tile: up to 64 x 128 keys
+#: largest filter the build kernel keeps resident in VMEM (v5e: 128 MiB)
+VMEM_FILTER_MAX = 64 << 20
 
 # murmur3 constants as numpy scalars: pallas kernels may not capture
 # module-level device arrays, but numpy scalars become in-trace literals
@@ -48,32 +70,13 @@ def _fmix32(h):
     return h
 
 
-def _hash_tile(lo, hi, k: int, log2nb: int):
-    """Vectorized per-tile hashing: block index + k in-block positions."""
-    h = _fmix32(lo ^ _fmix32(hi))
-    blk = (h >> jnp.uint32(32 - log2nb)).astype(jnp.int32) if log2nb > 0 \
-        else jnp.zeros_like(h, jnp.int32)
-    g1 = _fmix32(h ^ jnp.uint32(GOLDEN))
-    g2 = _fmix32(h ^ _P2) | jnp.uint32(1)
-    j = jnp.arange(k, dtype=jnp.uint32)
-    pos = (g1[:, None] + j[None, :] * g2[:, None]) & jnp.uint32(
-        BLOCK_BITS - 1)
-    return blk, pos
-
-
-def _update_rows(pos):
-    """Per-key 8-lane OR-update vectors from k bit positions: [n, LANES]."""
-    lane = (pos >> 5).astype(jnp.int32)               # [n, k]
-    bit = jnp.uint32(1) << (pos & jnp.uint32(31))     # [n, k]
-    lanes = jnp.arange(LANES, dtype=jnp.int32)        # [LANES]
-    onehot = (lane[:, :, None] == lanes[None, None, :])
-    # OR of one-bit values across k == sum when bits are distinct; use
-    # bitwise accumulation to stay exact under duplicate (lane,bit) pairs
-    upd = jnp.zeros((pos.shape[0], LANES), jnp.uint32)
-    for j in range(pos.shape[1]):                     # k is static, small
-        upd = upd | jnp.where(onehot[:, j, :], bit[:, j:j + 1],
-                              jnp.uint32(0))
-    return upd
+def _tile_rows(n: int) -> int:
+    """Rows of 128 keys per probe grid step: the largest power of two
+    up to `_MAX_ROWS` dividing n/128 (n % TILE == 0 keeps it >= 8)."""
+    rows, br = n // _LANE, _MAX_ROWS
+    while rows % br:
+        br //= 2
+    return br
 
 
 # --------------------------------------------------------------------------
@@ -81,75 +84,39 @@ def _update_rows(pos):
 # --------------------------------------------------------------------------
 
 
-def _probe_kernel(words_ref, lo_ref, hi_ref, out_ref, *, k: int,
-                  log2nb: int):
-    lo = lo_ref[0, :]
-    hi = hi_ref[0, :]
-    blk, pos = _hash_tile(lo, hi, k, log2nb)
-    words = words_ref[...]                            # filter resident
-    rows = words[blk]                                 # [TILE, LANES] gather
-    lane = (pos >> 5).astype(jnp.int32)
-    w = jnp.take_along_axis(rows, lane, axis=1)       # [TILE, k]
-    hits = (w >> (pos & jnp.uint32(31))) & jnp.uint32(1)
-    out_ref[0, :] = jnp.all(hits == 1, axis=1)
+def _probe_rows(words, lo, hi):
+    """XLA stage of a probe: block hash h [n] and each key's 256-bit
+    block row as 8 word planes [LANES, n], gathered from the HBM
+    filter."""
+    h = hashing.hash64(lo, hi)
+    blk = _block_index(h, words.shape[0]).astype(jnp.int32)
+    return h, words[blk].T
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("k", "interpret"))
-def probe_pallas(words: jnp.ndarray, lo: jnp.ndarray, hi: jnp.ndarray,
-                 k: int = DEFAULT_K, interpret: bool = True) -> jnp.ndarray:
-    """words [nblocks, LANES] uint32; lo/hi uint32 [n] (n % TILE == 0)."""
-    nblocks = words.shape[0]
-    log2nb = int(np.log2(nblocks))
-    n = lo.shape[0]
-    assert n % TILE == 0
-    g = n // TILE
-    lo2, hi2 = lo.reshape(g, TILE), hi.reshape(g, TILE)
-    out = pl.pallas_call(
-        functools.partial(_probe_kernel, k=k, log2nb=log2nb),
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec((nblocks, LANES), lambda i: (0, 0)),  # resident
-            pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-            pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g, TILE), jnp.bool_),
-        interpret=interpret,
-    )(words, lo2, hi2)
-    return out.reshape(n)
-
-
-# --------------------------------------------------------------------------
-# fused multi-filter probe: every filter incoming at a vertex in one kernel
-# (the device-resident data plane's per-vertex pass, DESIGN.md §15) — the
-# filters are concatenated into one resident stack, each probed on its own
-# key column, and the cumulative survivor mask after each filter is emitted
-# so the host can read live-count feedback from a single sync
-# --------------------------------------------------------------------------
-
-
-def _multi_probe_kernel(*refs, k: int, log2nbs: Tuple[int, ...],
-                        offsets: Tuple[int, ...]):
-    words_ref, out_ref = refs[0], refs[-1]
-    words = words_ref[...]                            # stacked, resident
+def _probe_kernel(*refs, k: int, m: int):
+    out_ref = refs[-1]
     ok = None
-    for f, log2nb in enumerate(log2nbs):
-        lo = refs[1 + 2 * f][0, :]
-        hi = refs[2 + 2 * f][0, :]
-        blk, pos = _hash_tile(lo, hi, k, log2nb)
-        rows = words[blk + offsets[f]]                # [TILE, LANES]
-        lane = (pos >> 5).astype(jnp.int32)
-        w = jnp.take_along_axis(rows, lane, axis=1)   # [TILE, k]
-        hits = (w >> (pos & jnp.uint32(31))) & jnp.uint32(1)
-        hit = jnp.all(hits == 1, axis=1)
-        ok = hit if ok is None else ok & hit
-        out_ref[f, :] = ok
+    for f in range(m):
+        h = refs[2 * f][...]
+        row_ref = refs[2 * f + 1]
+        rows = [row_ref[lane] for lane in range(LANES)]
+        g1 = _fmix32(h ^ GOLDEN)
+        g2 = _fmix32(h ^ _P2) | np.uint32(1)
+        for j in range(k):
+            pos = (g1 + np.uint32(j) * g2) & np.uint32(BLOCK_BITS - 1)
+            lane = pos >> 5
+            w = rows[0]
+            for x in range(1, LANES):
+                w = jnp.where(lane == np.uint32(x), rows[x], w)
+            hit = ((w >> (pos & np.uint32(31))) & np.uint32(1)) \
+                == np.uint32(1)
+            ok = hit if ok is None else ok & hit
+        out_ref[f] = ok.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def multi_probe_pallas(words_list, los, his, k: int = DEFAULT_K,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: Optional[bool] = None) -> jnp.ndarray:
     """Fused probe of m filters over m key columns of the same rows.
 
     `words_list`/`los`/`his` are equal-length tuples; every lo/hi is
@@ -157,32 +124,34 @@ def multi_probe_pallas(words_list, los, his, k: int = DEFAULT_K,
     cumulative survivor mask after filters 0..f — bit-identical to
     probing the filters one by one and ANDing."""
     m = len(words_list)
-    words = (words_list[0] if m == 1
-             else jnp.concatenate(words_list, axis=0))
-    log2nbs = tuple(int(np.log2(w.shape[0])) for w in words_list)
-    offs, acc = [], 0
-    for w in words_list:
-        offs.append(acc)
-        acc += w.shape[0]
     n = los[0].shape[0]
     assert n % TILE == 0
-    g = n // TILE
-    nb_total = words.shape[0]
-    tiles = []
-    for lo, hi in zip(los, his):
-        tiles.append(lo.reshape(g, TILE))
-        tiles.append(hi.reshape(g, TILE))
+    rows = n // _LANE
+    br = _tile_rows(n)
+    args, in_specs = [], []
+    for words, lo, hi in zip(words_list, los, his):
+        h, planes = _probe_rows(words, lo, hi)
+        args += [h.reshape(rows, _LANE),
+                 planes.reshape(LANES, rows, _LANE)]
+        in_specs += [pl.BlockSpec((br, _LANE), lambda i: (i, 0)),
+                     pl.BlockSpec((LANES, br, _LANE), lambda i: (0, i, 0))]
     out = pl.pallas_call(
-        functools.partial(_multi_probe_kernel, k=k, log2nbs=log2nbs,
-                          offsets=tuple(offs)),
-        grid=(g,),
-        in_specs=[pl.BlockSpec((nb_total, LANES), lambda i: (0, 0))]
-        + [pl.BlockSpec((1, TILE), lambda i: (i, 0))] * (2 * m),
-        out_specs=pl.BlockSpec((m, TILE), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.bool_),
-        interpret=interpret,
-    )(words, *tiles)
-    return out
+        functools.partial(_probe_kernel, k=k, m=m),
+        grid=(rows // br,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((m, br, _LANE), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, rows, _LANE), jnp.int32),
+        interpret=resolve_interpret(interpret),
+    )(*args)
+    return out.reshape(m, n) != 0
+
+
+def probe_pallas(words: jnp.ndarray, lo: jnp.ndarray, hi: jnp.ndarray,
+                 k: int = DEFAULT_K,
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
+    """words [nblocks, LANES] uint32; lo/hi uint32 [n] (n % TILE == 0)."""
+    return multi_probe_pallas((words,), (lo,), (hi,), k=k,
+                              interpret=interpret)[0]
 
 
 # --------------------------------------------------------------------------
@@ -190,88 +159,87 @@ def multi_probe_pallas(words_list, los, his, k: int = DEFAULT_K,
 # --------------------------------------------------------------------------
 
 
-def _build_kernel(lo_ref, hi_ref, mask_ref, out_ref, *, k: int,
-                  log2nb: int):
+def _insert_plan(lo, hi, mask, nblocks: int, k: int):
+    """XLA stage of a build: each key's block index (-1: not inserted)
+    and its k in-block bit positions (8 bits each), packed four to an
+    int32 word — the scalars the insert loop reads from SMEM."""
+    h = hashing.hash64(lo, hi)
+    blk = jnp.where(mask, _block_index(h, nblocks).astype(jnp.int32),
+                    jnp.int32(-1))
+    pos = _positions(h, k)
+    packed = []
+    for q in range(0, k, 4):
+        word = jnp.zeros(lo.shape, jnp.uint32)
+        for j in range(q, min(q + 4, k)):
+            word = word | (pos[:, j] << jnp.uint32(8 * (j - q)))
+        packed.append(jax.lax.bitcast_convert_type(word, jnp.int32))
+    return blk, packed
+
+
+def _build_kernel(blk_ref, *refs, k: int):
+    pos_refs, out_ref = refs[:-1], refs[-1]
+
     # zero the resident accumulator on the first grid step
     @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    lo = lo_ref[0, :]
-    hi = hi_ref[0, :]
-    mask = mask_ref[0, :]
-    blk, pos = _hash_tile(lo, hi, k, log2nb)
-    upd = _update_rows(pos)                           # [TILE, LANES]
-    upd = jnp.where(mask[:, None], upd, jnp.uint32(0))
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, _LANE), 1)
 
-    def body(i, _):
-        b = blk[i]
-        row = out_ref[b, :]
-        out_ref[b, :] = row | upd[i, :]
-        return 0
+    def insert(i, carry):
+        b = blk_ref[i]
 
-    jax.lax.fori_loop(0, lo.shape[0], body, 0)
+        @pl.when(b >= 0)
+        def _or_block():
+            first = (b & (_BLOCKS_PER_ROW - 1)) * LANES  # first lane
+            upd = jnp.zeros((1, _LANE), jnp.uint32)
+            for j in range(k):
+                p = (pos_refs[j // 4][i] >> (8 * (j % 4))) & 0xFF
+                bit = jnp.uint32(1) << (p & 31).astype(jnp.uint32)
+                upd = upd | jnp.where(lanes == first + (p >> 5), bit,
+                                      jnp.uint32(0))
+            r = b >> 4                              # its 128-lane row
+            out_ref[pl.ds(r, 1), :] = out_ref[pl.ds(r, 1), :] | upd
+
+        return carry
+
+    jax.lax.fori_loop(0, TILE, insert, 0)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("nblocks", "k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("nblocks", "k", "interpret"))
 def build_pallas(lo: jnp.ndarray, hi: jnp.ndarray, mask: jnp.ndarray,
                  nblocks: int, k: int = DEFAULT_K,
-                 interpret: bool = True) -> jnp.ndarray:
-    log2nb = int(np.log2(nblocks))
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
+    """Filter words uint32 [nblocks, LANES] from the `mask`ed keys."""
     n = lo.shape[0]
     assert n % TILE == 0
-    g = n // TILE
+    nbytes = nblocks * LANES * 4
+    if nbytes > VMEM_FILTER_MAX:
+        raise ValueError(
+            f"{nblocks}-block filter ({nbytes >> 20} MiB) exceeds the "
+            f"build kernel's VMEM budget ({VMEM_FILTER_MAX >> 20} MiB)")
+    rows = -(-nblocks // _BLOCKS_PER_ROW)
+    blk, packed = _insert_plan(lo, hi, mask, nblocks, k)
+    smem = pl.BlockSpec((TILE,), lambda i: (i,),
+                        memory_space=pltpu.SMEM)
     out = pl.pallas_call(
-        functools.partial(_build_kernel, k=k, log2nb=log2nb),
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-            pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-            pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((nblocks, LANES), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((nblocks, LANES), jnp.uint32),
-        interpret=interpret,
-    )(lo.reshape(g, TILE), hi.reshape(g, TILE), mask.reshape(g, TILE))
-    return out
+        functools.partial(_build_kernel, k=k),
+        grid=(n // TILE,),
+        in_specs=[smem] * (1 + len(packed)),
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((rows, _LANE), jnp.uint32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=rows * _LANE * 4 + (16 << 20)),
+        interpret=resolve_interpret(interpret),
+    )(blk, *packed)
+    return out.reshape(-1)[: nblocks * LANES].reshape(nblocks, LANES)
 
 
 # --------------------------------------------------------------------------
-# fused transfer (paper §3.2 filter transformation): one scan probes the
-# incoming filter and inserts survivors' outgoing keys into a fresh filter
+# transfer (paper §3.2 filter transformation): probe the incoming filter
+# on the incoming join key, insert survivors' outgoing keys into a fresh
+# filter — the probe and build kernels in one jit
 # --------------------------------------------------------------------------
-
-
-def _transfer_kernel(inw_ref, ilo_ref, ihi_ref, olo_ref, ohi_ref, mask_ref,
-                     ok_ref, outw_ref, *, k: int, log2nb_in: int,
-                     log2nb_out: int):
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        outw_ref[...] = jnp.zeros_like(outw_ref)
-
-    # probe the incoming filter on the incoming join key
-    ilo, ihi = ilo_ref[0, :], ihi_ref[0, :]
-    blk, pos = _hash_tile(ilo, ihi, k, log2nb_in)
-    rows = inw_ref[...][blk]
-    lane = (pos >> 5).astype(jnp.int32)
-    w = jnp.take_along_axis(rows, lane, axis=1)
-    hits = (w >> (pos & jnp.uint32(31))) & jnp.uint32(1)
-    ok = mask_ref[0, :] & jnp.all(hits == 1, axis=1)
-    ok_ref[0, :] = ok
-
-    # insert survivors' outgoing keys into the outgoing filter
-    olo, ohi = olo_ref[0, :], ohi_ref[0, :]
-    oblk, opos = _hash_tile(olo, ohi, k, log2nb_out)
-    upd = _update_rows(opos)
-    upd = jnp.where(ok[:, None], upd, jnp.uint32(0))
-
-    def body(i, _):
-        b = oblk[i]
-        outw_ref[b, :] = outw_ref[b, :] | upd[i, :]
-        return 0
-
-    jax.lax.fori_loop(0, olo.shape[0], body, 0)
 
 
 @functools.partial(jax.jit,
@@ -280,35 +248,9 @@ def transfer_pallas(in_words: jnp.ndarray,
                     in_lo: jnp.ndarray, in_hi: jnp.ndarray,
                     out_lo: jnp.ndarray, out_hi: jnp.ndarray,
                     mask: jnp.ndarray, nblocks_out: int,
-                    k: int = DEFAULT_K, interpret: bool = True
+                    k: int = DEFAULT_K, interpret: Optional[bool] = None
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    nblocks_in = in_words.shape[0]
-    n = in_lo.shape[0]
-    assert n % TILE == 0
-    g = n // TILE
-    shape2 = lambda a: a.reshape(g, TILE)
-    ok, outw = pl.pallas_call(
-        functools.partial(_transfer_kernel, k=k,
-                          log2nb_in=int(np.log2(nblocks_in)),
-                          log2nb_out=int(np.log2(nblocks_out))),
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec((nblocks_in, LANES), lambda i: (0, 0)),
-            pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-            pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-            pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-            pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-            pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-            pl.BlockSpec((nblocks_out, LANES), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((g, TILE), jnp.bool_),
-            jax.ShapeDtypeStruct((nblocks_out, LANES), jnp.uint32),
-        ],
-        interpret=interpret,
-    )(in_words, shape2(in_lo), shape2(in_hi), shape2(out_lo),
-      shape2(out_hi), shape2(mask))
-    return ok.reshape(n), outw
+    ok = mask & probe_pallas(in_words, in_lo, in_hi, k=k,
+                             interpret=interpret)
+    return ok, build_pallas(out_lo, out_hi, ok, nblocks_out, k=k,
+                            interpret=interpret)

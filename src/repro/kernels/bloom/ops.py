@@ -1,25 +1,18 @@
 """Public jit'd wrappers for the bloom Pallas kernels.
 
-Handles host-side key splitting, TILE padding, and interpret-mode
-selection (interpret=True unless running on a real TPU backend).
+Handles host-side key splitting and TILE padding; interpret mode is
+resolved by `repro.kernels.resolve_interpret`.
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import hashing
 from repro.core.bloom import DEFAULT_BITS_PER_KEY, DEFAULT_K, blocks_for
 from repro.kernels.bloom import bloom as _k
-
-
-def _interpret(flag: Optional[bool]) -> bool:
-    if flag is not None:
-        return flag
-    return jax.default_backend() != "tpu"
 
 
 def _pad_to_tile(a: np.ndarray, fill=0) -> np.ndarray:
@@ -45,7 +38,7 @@ def bloom_build(keys: np.ndarray, mask: Optional[np.ndarray] = None,
     lo, hi = hashing.key_halves(_pad_to_tile(keys))
     m = _pad_to_tile(np.asarray(mask, bool), False)
     return _k.build_pallas(jnp.asarray(lo), jnp.asarray(hi), jnp.asarray(m),
-                           nblocks, k=k, interpret=_interpret(interpret))
+                           nblocks, k=k, interpret=interpret)
 
 
 def bloom_probe(words: jnp.ndarray, keys: np.ndarray,
@@ -54,7 +47,7 @@ def bloom_probe(words: jnp.ndarray, keys: np.ndarray,
     keys = np.asarray(keys)
     lo, hi = hashing.key_halves(_pad_to_tile(keys))
     out = _k.probe_pallas(words, jnp.asarray(lo), jnp.asarray(hi), k=k,
-                          interpret=_interpret(interpret))
+                          interpret=interpret)
     return np.asarray(out)[: len(keys)]
 
 
@@ -78,5 +71,5 @@ def bloom_transfer(in_words: jnp.ndarray,
     ok, outw = _k.transfer_pallas(
         in_words, jnp.asarray(ilo), jnp.asarray(ihi), jnp.asarray(olo),
         jnp.asarray(ohi), jnp.asarray(m), nblocks_out, k=k,
-        interpret=_interpret(interpret))
+        interpret=interpret)
     return np.asarray(ok)[: len(in_keys)], outw

@@ -3,16 +3,10 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.flashattn import flashattn as _k
-
-
-def _interpret(flag: Optional[bool]) -> bool:
-    if flag is not None:
-        return flag
-    return jax.default_backend() != "tpu"
 
 
 def flash_attention(q, k, v, q_pos, kv_pos, kv_valid, *,
@@ -49,6 +43,7 @@ def flash_attention(q, k, v, q_pos, kv_pos, kv_valid, *,
     kvf = jnp.repeat(kv_valid, h, axis=0)
 
     out = _k.flash_pallas(qf, kf, vf, qpf, kpf, kvf, causal=causal,
-                          window=window, interpret=_interpret(interpret))
+                          window=window,
+                          interpret=resolve_interpret(interpret))
     out = out.reshape(b, h, sqp, d).transpose(0, 2, 1, 3)
     return out[:, :sq]

@@ -12,12 +12,6 @@ from repro.core import hashing
 from repro.kernels.semijoin import semijoin as _k
 
 
-def _interpret(flag: Optional[bool]) -> bool:
-    if flag is not None:
-        return flag
-    return jax.default_backend() != "tpu"
-
-
 def _pad_to_tile(a: np.ndarray, fill=0) -> np.ndarray:
     n = len(a)
     m = ((n + _k.TILE - 1) // _k.TILE) * _k.TILE
@@ -44,7 +38,7 @@ def semijoin_build(keys: np.ndarray, mask: Optional[np.ndarray] = None,
     lo, hi = hashing.key_halves(_pad_to_tile(keys))
     m = _pad_to_tile(np.asarray(mask, bool), False)
     return _k.build_pallas(jnp.asarray(lo), jnp.asarray(hi), jnp.asarray(m),
-                           cap, interpret=_interpret(interpret))
+                           cap, interpret=interpret)
 
 
 def semijoin_probe(table, keys: np.ndarray,
@@ -53,7 +47,7 @@ def semijoin_probe(table, keys: np.ndarray,
     keys = np.asarray(keys)
     lo, hi = hashing.key_halves(_pad_to_tile(keys))
     out = _k.probe_pallas(klo, khi, occ, jnp.asarray(lo), jnp.asarray(hi),
-                          interpret=_interpret(interpret))
+                          interpret=interpret)
     return np.asarray(out)[: len(keys)]
 
 
@@ -144,7 +138,7 @@ def joinmap_build(keys: np.ndarray, use_pallas: bool = True,
     if use_pallas:
         table = _k.build_rows_pallas(dp.to_device(lo), dp.to_device(hi),
                                      dp.to_device(mask), cap,
-                                     interpret=_interpret(interpret))
+                                     interpret=interpret)
     else:
         table = _joinmap_build_jnp(dp.to_device(lo), dp.to_device(hi),
                                    dp.to_device(mask), cap)
@@ -162,7 +156,7 @@ def joinmap_lookup(table, keys: np.ndarray, use_pallas: bool = True,
     if use_pallas:
         out = _k.lookup_pallas(klo, khi, occ, row, dp.to_device(lo),
                                dp.to_device(hi),
-                               interpret=_interpret(interpret))
+                               interpret=interpret)
     else:
         out = _joinmap_lookup_jnp(klo, khi, occ, row, dp.to_device(lo),
                                   dp.to_device(hi))
@@ -171,14 +165,22 @@ def joinmap_lookup(table, keys: np.ndarray, use_pallas: bool = True,
 
 # --------------------------------------------------------------------------
 # device sorted-segment join (the device-resident data plane, DESIGN.md
-# §15): duplicate-key joins entirely on device — stable lexicographic
-# argsort of the build keys, pair binary search, segment emission — with
-# the host syncing one output-size scalar per join. Bit-identical
-# (build_idx, probe_idx) to `engine_join.sorted_join_indices`: signed
-# int64 keys are compared as (hi ^ sign, lo) unsigned pairs, and a
-# leading invalid bit sorts NULL-key and padding rows past every real
-# key so they can never match (NULL-key probe rows are handled by
-# zeroing their match counts — no compact-and-remap on either side).
+# §15): duplicate-key joins on device — pair binary search of every probe
+# key into the sorted build side, segment emission — with the host
+# syncing one output-size scalar per join. Bit-identical (build_idx,
+# probe_idx) to `engine_join.sorted_join_indices`: signed int64 keys are
+# compared as (hi ^ sign, lo) unsigned pairs, and a leading invalid bit
+# sorts NULL-key and padding rows past every real key so they can never
+# match (NULL-key probe rows are handled by zeroing their match counts —
+# no compact-and-remap on either side).
+#
+# The build side's stable order is computed on host, where its keys
+# already are, and travels sorted in the build upload: XLA's TPU
+# compiler takes 10-45 s per shape for a single-key sort at 2^14-2^23
+# rows, and minutes for a three-key one. Everything past the sort —
+# searches, counts, selection, emission — runs on device, with
+# `bloom.prefix_sum` + binary search in place of scatters and cumsums
+# for the same reason.
 # --------------------------------------------------------------------------
 
 _SIGN = np.uint32(0x80000000)
@@ -196,23 +198,14 @@ def _pad_pow2(a: np.ndarray, m: int, fill=0) -> np.ndarray:
     return out
 
 
-def _lex3_argsort(lo, hi_f, inv):
-    """Stable argsort by (inv, hi_f, lo): three stable passes (LSD) ==
-    one stable sort on the composite — the exact permutation
-    `np.argsort(key, kind="stable")` yields over the valid rows."""
-    perm = jnp.argsort(lo, stable=True)
-    perm = perm[jnp.argsort(hi_f[perm], stable=True)]
-    return perm[jnp.argsort(inv[perm], stable=True)]
-
-
 def _search3(slo, shi, sinv, qlo, qhi, right: bool):
     """searchsorted over (inv, hi, lo) triples for queries with inv=0,
-    as a static log2(n) binary-search ladder (no pair-valued
-    searchsorted primitive on device)."""
+    as a log2(n)-step binary-search loop (no pair-valued searchsorted
+    primitive on device)."""
     n = slo.shape[0]
-    lo_b = jnp.zeros(qlo.shape, jnp.int32)
-    hi_b = jnp.full(qlo.shape, n, jnp.int32)
-    for _ in range(max(1, int(n).bit_length())):
+
+    def step(_, bounds):
+        lo_b, hi_b = bounds
         mid = (lo_b + hi_b) >> 1
         midc = jnp.minimum(mid, n - 1)
         mlo, mhi, minv = slo[midc], shi[midc], sinv[midc]
@@ -222,9 +215,13 @@ def _search3(slo, shi, sinv, qlo, qhi, right: bool):
             lt = (mhi < qhi) | ((mhi == qhi) & (mlo < qlo))
         active = lo_b < hi_b
         go = active & (minv == 0) & lt
-        lo_b = jnp.where(go, mid + 1, lo_b)
-        hi_b = jnp.where(active & ~go, mid, hi_b)
-    return lo_b
+        return (jnp.where(go, mid + 1, lo_b),
+                jnp.where(active & ~go, mid, hi_b))
+
+    bounds = (jnp.zeros(qlo.shape, jnp.int32),
+              jnp.full(qlo.shape, n, jnp.int32))
+    return jax.lax.fori_loop(0, max(1, int(n).bit_length()), step,
+                             bounds)[0]
 
 
 @jax.jit
@@ -233,14 +230,13 @@ def _segjoin_counts(bstack, pstack, np_live):
     first-match position in it, and its match count (0 past `np_live`).
 
     Both sides arrive as one stacked uint32 upload each — build planes
-    (lo, hi_flipped, invalid), probe planes (lo, hi_flipped[, valid]) —
-    so a join costs two h2d transfers however many key planes it needs.
-    A probe validity plane (shape-selected at trace time) zeroes invalid
-    rows' counts: inner drops them, left emits them unmatched, anti
-    keeps them, all in probe order with no compact-and-remap."""
-    blo, bhi_f, binv = bstack[0], bstack[1], bstack[2]
-    order = _lex3_argsort(blo, bhi_f, binv)
-    slo, shi, sinv = blo[order], bhi_f[order], binv[order]
+    in sorted order (lo, hi_flipped, invalid, order), probe planes (lo,
+    hi_flipped[, valid]) — so a join costs two h2d transfers however
+    many key planes it needs. A probe validity plane (shape-selected at
+    trace time) zeroes invalid rows' counts: inner drops them, left
+    emits them unmatched, anti keeps them, all in probe order with no
+    compact-and-remap."""
+    slo, shi, sinv = bstack[0], bstack[1], bstack[2]
     plo, phi_f = pstack[0], pstack[1]
     lo_pos = _search3(slo, shi, sinv, plo, phi_f, right=False)
     hi_pos = _search3(slo, shi, sinv, plo, phi_f, right=True)
@@ -248,18 +244,18 @@ def _segjoin_counts(bstack, pstack, np_live):
     if pstack.shape[0] == 3:
         live = live & (pstack[2] != 0)
     counts = jnp.where(live, hi_pos - lo_pos, 0)
-    return order.astype(jnp.int32), lo_pos, counts
+    return bstack[3].astype(jnp.int32), lo_pos, counts
 
 
 @functools.partial(jax.jit, static_argnames=("want_zero",))
 def _segjoin_sel(counts, np_live, want_zero: bool):
     """Probe-row selection for semi (counts > 0) / anti (counts == 0),
     packed ascending, plus its device count."""
+    from repro.core.bloom import flatnonzero
     n = counts.shape[0]
     live = jnp.arange(n, dtype=jnp.int32) < np_live
     ok = live & ((counts == 0) if want_zero else (counts > 0))
-    sel = jnp.nonzero(ok, size=n, fill_value=0)[0].astype(jnp.int32)
-    return sel, jnp.sum(ok, dtype=jnp.int32)
+    return flatnonzero(ok, n), jnp.sum(ok, dtype=jnp.int32)
 
 
 @jax.jit
@@ -278,14 +274,18 @@ def _segjoin_outcounts_left(counts, np_live):
 def _segjoin_emit(order, lo_pos, counts, out_counts, total_len: int,
                   left: bool):
     """Match-pair emission: probe rows in original order, matches in
-    stable build-key order (the engine output contract). Rows past the
-    true total are `jnp.repeat` padding; the caller slices them off."""
+    stable build-key order (the engine output contract). Output slot t
+    belongs to the first probe row whose running output count exceeds
+    t (a binary search over the cumsum, not a scatter-based repeat).
+    Rows past the true total are padding; the caller slices them off."""
+    from repro.core.bloom import prefix_sum
     npb = counts.shape[0]
-    starts = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                              jnp.cumsum(out_counts, dtype=jnp.int32)])
-    probe_idx = jnp.repeat(jnp.arange(npb, dtype=jnp.int32), out_counts,
-                           total_repeat_length=total_len)
-    within = jnp.arange(total_len, dtype=jnp.int32) - starts[probe_idx]
+    ends = prefix_sum(out_counts)
+    slot = jnp.arange(total_len, dtype=jnp.int32)
+    probe_idx = jnp.minimum(
+        jnp.searchsorted(ends, slot, side="right", method="scan"),
+        npb - 1).astype(jnp.int32)
+    within = slot - (ends - out_counts)[probe_idx]
     build_pos = lo_pos[probe_idx] + within
     build_idx = order[jnp.clip(build_pos, 0, order.shape[0] - 1)]
     if left:
@@ -310,15 +310,23 @@ def segment_join_device(build_key: np.ndarray, probe_key: np.ndarray,
     nb, npr = len(build_key), len(probe_key)
     bb, pb = _pow2(nb), _pow2(npr)
 
-    blo, bhi = hashing.key_halves(_pad_pow2(build_key, bb))
-    bstack = np.empty((3, bb), np.uint32)
+    # stable order by (invalid, key): valid rows by key, then NULL-key
+    # and padding rows in row order — `np.argsort(kind="stable")` over
+    # the valid rows, as the reference sorts them
+    live = np.arange(nb) if build_valid is None \
+        else np.flatnonzero(np.asarray(build_valid, bool))
+    keep = np.zeros(bb, bool)
+    keep[live] = True
+    dead = np.flatnonzero(~keep)
+    order = np.concatenate(
+        [live[np.argsort(build_key[live], kind="stable")], dead])
+    blo, bhi = hashing.key_halves(_pad_pow2(build_key, bb)[order])
+    bstack = np.empty((4, bb), np.uint32)
     bstack[0] = blo
     bstack[1] = bhi ^ _SIGN
-    binv = np.zeros(bb, np.uint32)
-    binv[nb:] = 1
-    if build_valid is not None:
-        binv[:nb][~np.asarray(build_valid, bool)] = 1
-    bstack[2] = binv
+    bstack[2] = 0
+    bstack[2, len(live):] = 1
+    bstack[3] = order
     plo, phi = hashing.key_halves(_pad_pow2(probe_key, pb))
     pstack = np.empty((3 if probe_valid is not None else 2, pb),
                       np.uint32)

@@ -2,10 +2,16 @@
 
 This is the Yannakakis baseline's primitive (paper §2.2) in TPU form: the
 pointer-chasing hash map becomes a flat power-of-two table of (lo, hi)
-uint32 key halves plus an occupancy lane, linear probing bounded by the
-table's load factor. Build is a serialized read-modify-write loop (like
-any hash insert); probe is tile-vectorized with a while-loop over probe
-displacement that terminates when every lane in the tile has resolved.
+uint32 key halves plus an occupancy lane (and, for the join map, a row
+lane), linear probing bounded by the table's load factor.
+
+Layout, as the v5e compiler accepts it: the tables stay resident in VMEM
+for the whole grid in their lane-dense (cap/128, 128) view; each key's
+halves and slot hash (computed by XLA ahead of the kernel) arrive in SMEM
+tiles of TILE keys. Both build and lookup are serialized scalar loops —
+the vector unit has no gather from VMEM — that read a slot by loading its
+(1, 128) row and reducing the one lane out, and write it by a masked
+row store. Tables above `VMEM_TABLE_MAX` are refused.
 
 The cost asymmetry between this kernel and `kernels/bloom` — dependent
 probes and a large VMEM-resident table vs. one 256-bit block fetch — is
@@ -14,13 +20,20 @@ exactly the β ≪ 1 asymmetry the paper's cost model builds on.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 TILE = 1024
+_LANE = 128
+#: VMEM all tables of one call may take (v5e: 128 MiB)
+VMEM_TABLE_MAX = 96 << 20
 
 _C1 = np.uint32(0x85EBCA6B)
 _C2 = np.uint32(0xC2B2AE35)
@@ -39,281 +52,166 @@ def _slot_hash(lo, hi):
     return _fmix32(lo ^ _fmix32(hi))
 
 
+def _as_i32(a):
+    return jax.lax.bitcast_convert_type(a, jnp.int32)
+
+
+def _as_u32(a):
+    return jax.lax.bitcast_convert_type(a, jnp.uint32)
+
+
+def _slot_reader(refs, interpret: bool):
+    """`get(t, s)`: slot `s` of table `refs[t]` as a scalar. Compiled:
+    load the slot's (1, 128) row and reduce its lane out. Interpret
+    mode snapshots the tables as values first (a while loop whose
+    condition reads a ref has no discharge rule in the interpreter)."""
+    if interpret:
+        vals = [r[...] for r in refs]
+        return lambda t, s: vals[t][s >> 7, s & (_LANE - 1)]
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, _LANE), 1)
+
+    def get(t, s):
+        row = refs[t][pl.ds(s >> 7, 1), :]
+        return jnp.sum(jnp.where(lanes == (s & (_LANE - 1)), row, 0))
+    return get
+
+
+def _find(get, h, lo, hi, cap: int):
+    """Linear probe from `h`'s home slot to the first slot that is empty
+    or holds (lo, hi)."""
+    def cond(s):
+        return (get(0, s) != 0) & ~((get(1, s) == lo) & (get(2, s) == hi))
+
+    return jax.lax.while_loop(cond, lambda s: (s + 1) & (cap - 1),
+                              h & (cap - 1))
+
+
+def _vmem_params(table_bytes: int):
+    if table_bytes > VMEM_TABLE_MAX:
+        raise ValueError(
+            f"hash tables of {table_bytes >> 20} MiB exceed the VMEM "
+            f"budget ({VMEM_TABLE_MAX >> 20} MiB)")
+    return pltpu.CompilerParams(vmem_limit_bytes=table_bytes + (16 << 20))
+
+
+def _key_plan(lo, hi):
+    """SMEM-bound per-key scalars: slot hash, lo, hi (as int32)."""
+    return _as_i32(_slot_hash(lo, hi)), _as_i32(lo), _as_i32(hi)
+
+
+_SMEM_TILE = pl.BlockSpec((TILE,), lambda i: (i,), memory_space=pltpu.SMEM)
+
+
 # --------------------------------------------------------------------------
-# build
+# build: (klo, khi, occ, row) map; duplicate keys dedup into one slot and
+# the row lane keeps the last one — the join engine only uses the map for
+# duplicate-free build sides, detected from the occupancy count
 # --------------------------------------------------------------------------
 
 
-def _build_kernel(lo_ref, hi_ref, mask_ref, klo_ref, khi_ref, occ_ref,
-                  *, cap: int, interpret: bool):
+def _build_rows_kernel(h_ref, lo_ref, hi_ref, mask_ref, occ_ref, klo_ref,
+                       khi_ref, row_ref, *, cap: int, interpret: bool):
     @pl.when(pl.program_id(0) == 0)
     def _init():
-        occ_ref[...] = jnp.zeros_like(occ_ref)
-        klo_ref[...] = jnp.zeros_like(klo_ref)
-        khi_ref[...] = jnp.zeros_like(khi_ref)
+        for r in (occ_ref, klo_ref, khi_ref, row_ref):
+            r[...] = jnp.zeros_like(r)
 
-    lo = lo_ref[0, :]
-    hi = hi_ref[0, :]
-    mask = mask_ref[0, :]
-    h = _slot_hash(lo, hi)
-
-    def insert(i, _):
-        if interpret:
-            # snapshot the table as values: within one insert the table
-            # is read-only, and keeping refs out of the while_loop lets
-            # interpret mode discharge the state (while-with-ref-cond
-            # has no discharge rule)
-            occ = occ_ref[0, :]
-            klo = klo_ref[0, :]
-            khi = khi_ref[0, :]
-
-            def slot_state(s):
-                return occ[s], klo[s], khi[s]
-        else:
-            # compiled mode keeps per-slot scalar ref reads — a
-            # full-table snapshot per insert would be O(n*cap) traffic
-            def slot_state(s):
-                return occ_ref[0, s], klo_ref[0, s], khi_ref[0, s]
-
-        def find(slot):
-            # advance until empty slot or the same key (dedup insert)
-            def cond(s):
-                s_occ, s_lo, s_hi = slot_state(s)
-                occupied = s_occ != 0
-                same = (s_lo == lo[i]) & (s_hi == hi[i])
-                return occupied & ~same
-
-            def step(s):
-                return (s + 1) & (cap - 1)
-
-            return jax.lax.while_loop(cond, step, slot)
-
-        slot0 = (h[i] & jnp.uint32(cap - 1)).astype(jnp.int32)
-        slot = find(slot0)
-
-        @pl.when(mask[i])
-        def _store():
-            klo_ref[0, slot] = lo[i]
-            khi_ref[0, slot] = hi[i]
-            occ_ref[0, slot] = jnp.uint32(1)
-
-        return 0
-
-    jax.lax.fori_loop(0, lo.shape[0], insert, 0)
-
-
-@functools.partial(jax.jit, static_argnames=("cap", "interpret"))
-def build_pallas(lo, hi, mask, cap: int, interpret: bool = True):
-    n = lo.shape[0]
-    assert n % TILE == 0 and cap & (cap - 1) == 0
-    g = n // TILE
-    klo, khi, occ = pl.pallas_call(
-        functools.partial(_build_kernel, cap=cap, interpret=interpret),
-        grid=(g,),
-        in_specs=[pl.BlockSpec((1, TILE), lambda i: (i, 0))] * 3,
-        out_specs=[pl.BlockSpec((1, cap), lambda i: (0, 0))] * 3,
-        out_shape=[jax.ShapeDtypeStruct((1, cap), jnp.uint32)] * 3,
-        interpret=interpret,
-    )(lo.reshape(g, TILE), hi.reshape(g, TILE),
-      mask.reshape(g, TILE).astype(jnp.uint32))
-    return klo[0], khi[0], occ[0]
-
-
-# --------------------------------------------------------------------------
-# probe
-# --------------------------------------------------------------------------
-
-
-def _probe_kernel(klo_ref, khi_ref, occ_ref, lo_ref, hi_ref, out_ref,
-                  *, cap: int):
-    lo = lo_ref[0, :]
-    hi = hi_ref[0, :]
-    h = _slot_hash(lo, hi)
-    slot = (h & jnp.uint32(cap - 1)).astype(jnp.int32)
-    klo = klo_ref[0, :]
-    khi = khi_ref[0, :]
-    occ = occ_ref[0, :]
-
-    def cond(state):
-        _, resolved, _ = state
-        return ~jnp.all(resolved)
-
-    def step(state):
-        slot, resolved, found = state
-        s_lo = klo[slot]
-        s_hi = khi[slot]
-        s_occ = occ[slot] != 0
-        hit = s_occ & (s_lo == lo) & (s_hi == hi)
-        miss = ~s_occ
-        found = found | (hit & ~resolved)
-        resolved = resolved | hit | miss
-        slot = jnp.where(resolved, slot, (slot + 1) & (cap - 1))
-        return slot, resolved, found
-
-    init = (slot, jnp.zeros_like(lo, jnp.bool_), jnp.zeros_like(lo, jnp.bool_))
-    _, _, found = jax.lax.while_loop(cond, step, init)
-    out_ref[0, :] = found
-
-
-# --------------------------------------------------------------------------
-# joinmap: build with row payload + lookup (the join runtime's primitive)
-# --------------------------------------------------------------------------
-
-
-def _build_rows_kernel(lo_ref, hi_ref, mask_ref, klo_ref, khi_ref, occ_ref,
-                       row_ref, *, cap: int, interpret: bool):
-    """`_build_kernel` plus a row-index lane: slot -> originating build
-    row, so a probe hit resolves to a join partner, not just membership.
-    Duplicate keys overwrite the row lane (last wins) — the join engine
-    only takes this path for duplicate-free build sides, detected from
-    the occupancy count."""
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        occ_ref[...] = jnp.zeros_like(occ_ref)
-        klo_ref[...] = jnp.zeros_like(klo_ref)
-        khi_ref[...] = jnp.zeros_like(khi_ref)
-        row_ref[...] = jnp.zeros_like(row_ref)
-
-    lo = lo_ref[0, :]
-    hi = hi_ref[0, :]
-    mask = mask_ref[0, :]
-    h = _slot_hash(lo, hi)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, _LANE), 1)
     base = pl.program_id(0) * TILE
 
-    def insert(i, _):
-        if interpret:
-            occ = occ_ref[0, :]
-            klo = klo_ref[0, :]
-            khi = khi_ref[0, :]
+    def put(ref, s, v):
+        r = s >> 7
+        ref[pl.ds(r, 1), :] = jnp.where(lanes == (s & (_LANE - 1)), v,
+                                        ref[pl.ds(r, 1), :])
 
-            def slot_state(s):
-                return occ[s], klo[s], khi[s]
-        else:
-            def slot_state(s):
-                return occ_ref[0, s], klo_ref[0, s], khi_ref[0, s]
+    def insert(i, carry):
+        get = _slot_reader((occ_ref, klo_ref, khi_ref), interpret)
+        lo, hi = lo_ref[i], hi_ref[i]
+        slot = _find(get, h_ref[i], lo, hi, cap)
 
-        def find(slot):
-            def cond(s):
-                s_occ, s_lo, s_hi = slot_state(s)
-                occupied = s_occ != 0
-                same = (s_lo == lo[i]) & (s_hi == hi[i])
-                return occupied & ~same
-
-            def step(s):
-                return (s + 1) & (cap - 1)
-
-            return jax.lax.while_loop(cond, step, slot)
-
-        slot0 = (h[i] & jnp.uint32(cap - 1)).astype(jnp.int32)
-        slot = find(slot0)
-
-        @pl.when(mask[i])
+        @pl.when(mask_ref[i] != 0)
         def _store():
-            klo_ref[0, slot] = lo[i]
-            khi_ref[0, slot] = hi[i]
-            occ_ref[0, slot] = jnp.uint32(1)
-            row_ref[0, slot] = (base + i).astype(jnp.uint32)
+            put(klo_ref, slot, lo)
+            put(khi_ref, slot, hi)
+            put(occ_ref, slot, 1)
+            put(row_ref, slot, base + i)
 
-        return 0
+        return carry
 
-    jax.lax.fori_loop(0, lo.shape[0], insert, 0)
+    jax.lax.fori_loop(0, TILE, insert, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "interpret"))
-def build_rows_pallas(lo, hi, mask, cap: int, interpret: bool = True):
+def build_rows_pallas(lo, hi, mask, cap: int,
+                      interpret: Optional[bool] = None):
+    """(klo, khi, occ, row) uint32 [cap] tables from uint32 key halves
+    [n] (n % TILE == 0); rows with mask False are not inserted."""
     n = lo.shape[0]
-    assert n % TILE == 0 and cap & (cap - 1) == 0
-    g = n // TILE
-    klo, khi, occ, row = pl.pallas_call(
-        functools.partial(_build_rows_kernel, cap=cap, interpret=interpret),
-        grid=(g,),
-        in_specs=[pl.BlockSpec((1, TILE), lambda i: (i, 0))] * 3,
-        out_specs=[pl.BlockSpec((1, cap), lambda i: (0, 0))] * 4,
-        out_shape=[jax.ShapeDtypeStruct((1, cap), jnp.uint32)] * 4,
+    assert n % TILE == 0 and cap & (cap - 1) == 0 and cap >= _LANE
+    interpret = resolve_interpret(interpret)
+    tables = pl.pallas_call(
+        functools.partial(_build_rows_kernel, cap=cap,
+                          interpret=interpret),
+        grid=(n // TILE,),
+        in_specs=[_SMEM_TILE] * 4,
+        out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 4,
+        out_shape=[jax.ShapeDtypeStruct((cap // _LANE, _LANE),
+                                        jnp.int32)] * 4,
+        compiler_params=_vmem_params(4 * cap * 4),
         interpret=interpret,
-    )(lo.reshape(g, TILE), hi.reshape(g, TILE),
-      mask.reshape(g, TILE).astype(jnp.uint32))
-    return klo[0], khi[0], occ[0], row[0]
+    )(*_key_plan(lo, hi), mask.astype(jnp.int32))
+    occ, klo, khi, row = (_as_u32(t.reshape(cap)) for t in tables)
+    return klo, khi, occ, row
 
 
-def _lookup_kernel(klo_ref, khi_ref, occ_ref, row_ref, lo_ref, hi_ref,
-                   out_ref, *, cap: int):
-    """Tile-vectorized lookup: matched build row index, -1 on miss."""
-    lo = lo_ref[0, :]
-    hi = hi_ref[0, :]
-    h = _slot_hash(lo, hi)
-    slot = (h & jnp.uint32(cap - 1)).astype(jnp.int32)
-    klo = klo_ref[0, :]
-    khi = khi_ref[0, :]
-    occ = occ_ref[0, :]
-    row = row_ref[0, :]
+def build_pallas(lo, hi, mask, cap: int, interpret: Optional[bool] = None):
+    """(klo, khi, occ) membership table — the join map minus its row
+    lane."""
+    return build_rows_pallas(lo, hi, mask, cap, interpret=interpret)[:3]
 
-    def cond(state):
-        _, resolved, _ = state
-        return ~jnp.all(resolved)
 
-    def step(state):
-        slot, resolved, ans = state
-        s_lo = klo[slot]
-        s_hi = khi[slot]
-        s_occ = occ[slot] != 0
-        hit = s_occ & (s_lo == lo) & (s_hi == hi)
-        miss = ~s_occ
-        ans = jnp.where(hit & ~resolved, row[slot].astype(jnp.int32), ans)
-        resolved = resolved | hit | miss
-        slot = jnp.where(resolved, slot, (slot + 1) & (cap - 1))
-        return slot, resolved, ans
+# --------------------------------------------------------------------------
+# lookup: matched build row per probe key (-1 on miss)
+# --------------------------------------------------------------------------
 
-    init = (slot, jnp.zeros_like(lo, jnp.bool_),
-            jnp.full(lo.shape, -1, jnp.int32))
-    _, _, ans = jax.lax.while_loop(cond, step, init)
-    out_ref[0, :] = ans
+
+def _lookup_kernel(h_ref, lo_ref, hi_ref, occ_ref, klo_ref, khi_ref,
+                   row_ref, out_ref, *, cap: int, interpret: bool):
+    get = _slot_reader((occ_ref, klo_ref, khi_ref, row_ref), interpret)
+
+    def lookup(i, carry):
+        slot = _find(get, h_ref[i], lo_ref[i], hi_ref[i], cap)
+        out_ref[i] = jnp.where(get(0, slot) != 0, get(3, slot), -1)
+        return carry
+
+    jax.lax.fori_loop(0, TILE, lookup, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def lookup_pallas(klo, khi, occ, row, lo, hi, interpret: bool = True):
+def lookup_pallas(klo, khi, occ, row, lo, hi,
+                  interpret: Optional[bool] = None):
+    """int32 [n]: the `row` lane of each probe key's slot, -1 on miss."""
     cap = klo.shape[0]
     n = lo.shape[0]
     assert n % TILE == 0
-    g = n // TILE
-    out = pl.pallas_call(
-        functools.partial(_lookup_kernel, cap=cap),
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec((1, cap), lambda i: (0, 0)),
-            pl.BlockSpec((1, cap), lambda i: (0, 0)),
-            pl.BlockSpec((1, cap), lambda i: (0, 0)),
-            pl.BlockSpec((1, cap), lambda i: (0, 0)),
-            pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-            pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g, TILE), jnp.int32),
+    interpret = resolve_interpret(interpret)
+    table = pl.BlockSpec(memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        functools.partial(_lookup_kernel, cap=cap, interpret=interpret),
+        grid=(n // TILE,),
+        in_specs=[_SMEM_TILE] * 3 + [table] * 4,
+        out_specs=_SMEM_TILE,
+        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
+        compiler_params=_vmem_params(4 * cap * 4),
         interpret=interpret,
-    )(klo[None, :], khi[None, :], occ[None, :], row[None, :],
-      lo.reshape(g, TILE), hi.reshape(g, TILE))
-    return out.reshape(n)
+    )(*_key_plan(lo, hi),
+      *(_as_i32(t).reshape(cap // _LANE, _LANE)
+        for t in (occ, klo, khi, row)))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def probe_pallas(klo, khi, occ, lo, hi, interpret: bool = True):
-    cap = klo.shape[0]
-    n = lo.shape[0]
-    assert n % TILE == 0
-    g = n // TILE
-    out = pl.pallas_call(
-        functools.partial(_probe_kernel, cap=cap),
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec((1, cap), lambda i: (0, 0)),
-            pl.BlockSpec((1, cap), lambda i: (0, 0)),
-            pl.BlockSpec((1, cap), lambda i: (0, 0)),
-            pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-            pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, TILE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((g, TILE), jnp.bool_),
-        interpret=interpret,
-    )(klo[None, :], khi[None, :], occ[None, :],
-      lo.reshape(g, TILE), hi.reshape(g, TILE))
-    return out.reshape(n)
+def probe_pallas(klo, khi, occ, lo, hi, interpret: Optional[bool] = None):
+    """bool [n]: probe key present in the membership table. The lookup
+    kernel with the occupancy lane standing in for the row lane (1 on a
+    hit, -1 on a miss)."""
+    return lookup_pallas(klo, khi, occ, occ, lo, hi,
+                         interpret=interpret) >= 0

@@ -1,100 +1,13 @@
-"""Production mesh construction + JAX version-compat shims.
+"""Production mesh construction.
 
 Defined as functions (never module-level constants) so importing this
 module touches no jax device state. Single pod = 16x16 (256 v5e chips,
 axes data x model); multi-pod adds a leading "pod" axis (2 x 256 = 512).
-
-Compat: the codebase targets the current jax mesh API
-(`jax.set_mesh`, `jax.sharding.get_abstract_mesh`, `AxisType`,
-`jax.make_mesh(..., axis_types=...)`). Older jax (<= 0.4.x, the version
-baked into some runtime images) predates all four; `install_jax_compat`
-fills the gaps from the legacy thread-resources mesh context so the rest
-of the tree can use one spelling. It only ever *adds* missing
-attributes — on a current jax it is a no-op.
 """
 from __future__ import annotations
 
-import contextlib
-import enum
-
 import jax
-
-try:                                        # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                         # pragma: no cover - version dep
-    class AxisType(enum.Enum):
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-def _legacy_ambient_mesh():
-    """The mesh made ambient by `with mesh:` on old jax (or None)."""
-    from jax._src import mesh as mesh_lib
-    m = getattr(mesh_lib.thread_resources.env, "physical_mesh", None)
-    if m is None or m.empty:
-        return None
-    return m
-
-
-def get_abstract_mesh():
-    """Ambient mesh; an empty/None result means "no mesh set"."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None and fn is not get_abstract_mesh:
-        return fn()
-    return _legacy_ambient_mesh()
-
-
-@contextlib.contextmanager
-def set_mesh(mesh):
-    """`with set_mesh(m):` — the new-jax spelling on any version."""
-    fn = getattr(jax, "set_mesh", None)
-    if fn is not None and fn is not set_mesh:
-        with fn(mesh):
-            yield mesh
-    else:                                   # legacy: Mesh is a ctx manager
-        with mesh:
-            yield mesh
-
-
-def install_jax_compat() -> None:
-    """Backfill removed/renamed jax attrs used across the tree.
-
-    Installed at import of this module; call sites that spell
-    `jax.set_mesh` / `jax.sharding.get_abstract_mesh` directly (tests,
-    notebooks) then work on old jax too.
-    """
-    import inspect
-    if not hasattr(jax.sharding, "get_abstract_mesh"):
-        jax.sharding.get_abstract_mesh = get_abstract_mesh
-    if not hasattr(jax.sharding, "AxisType"):
-        jax.sharding.AxisType = AxisType
-    if not hasattr(jax, "set_mesh"):
-        jax.set_mesh = set_mesh
-    if not hasattr(jax, "shard_map"):
-        from jax.experimental.shard_map import shard_map
-        jax.shard_map = shard_map
-    if not hasattr(jax, "make_mesh"):           # pre-0.4.35
-        def make_mesh(axis_shapes, axis_names, *, axis_types=None,
-                      devices=None):
-            from jax.sharding import Mesh
-            from jax.experimental import mesh_utils
-            devs = mesh_utils.create_device_mesh(tuple(axis_shapes),
-                                                 devices=devices)
-            return Mesh(devs, tuple(axis_names))
-
-        jax.make_mesh = make_mesh
-    elif "axis_types" not in inspect.signature(jax.make_mesh).parameters:
-        orig = jax.make_mesh
-
-        def make_mesh(axis_shapes, axis_names, *args, axis_types=None,
-                      **kw):
-            return orig(axis_shapes, axis_names, *args, **kw)
-
-        jax.make_mesh = make_mesh
-
-
-install_jax_compat()
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):

@@ -16,16 +16,13 @@ EXPERIMENTS.md §Perf iteration 1).
 """
 from __future__ import annotations
 
-
 import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.mesh import get_abstract_mesh
-
 
 def _mesh():
-    m = get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     return m if m is not None and m.axis_names else None
 
 

@@ -130,9 +130,20 @@ def test_track_restores_previous_context():
 # --------------------------------------------------------------------------
 
 
+def _engine(backend):
+    if backend == "jax-tpu":
+        # the routing JaxEngine takes on a TPU: device-resident scans,
+        # filter builds on the host mirror
+        from repro.core.engine_bloom import JaxEngine
+        eng = JaxEngine(device_resident=True)
+        eng.host_build = True
+        return eng
+    return get_engine(backend)
+
+
 def _scan_outputs(backend, mask, keys, keys2, raw, out_keys, valid,
                   words1, words2, nblocks):
-    eng = get_engine(backend)
+    eng = _engine(backend)
     scan = eng.begin(mask)
     scan.probe([(words1, eng.keys(keys)), (words2, eng.keys(keys2))])
     after_probe = np.asarray(device_plane.to_host(scan.mask)).copy()
@@ -148,12 +159,12 @@ def _scan_outputs(backend, mask, keys, keys2, raw, out_keys, valid,
             "words": np.asarray(device_plane.to_host(words)).copy()}
 
 
-@pytest.mark.parametrize("backend", ["jax", "pallas"])
+@pytest.mark.parametrize("backend", ["jax", "pallas", "jax-tpu"])
 def test_fused_scan_matches_numpy_oracle(rng, backend):
     """One fused probe->range-cut->build scan, bit-exact vs the host
     engine: surviving mask after each stage, per-filter live counts,
     device key ranges (plain and NULL-masked), emitted filter words."""
-    n = 3000 if backend == "jax" else 600
+    n = 600 if backend == "pallas" else 3000
     keys = rng.integers(0, 900, n).astype(np.int64)
     keys2 = rng.integers(0, 900, n).astype(np.int64)
     raw = rng.integers(-500, 500, n).astype(np.int64)
@@ -250,6 +261,35 @@ def test_segment_join_device_empty_survivors(how):
     _check_segjoin(bk, pk, how, np.zeros(3, bool), None)
     _check_segjoin(bk, pk, how, None, np.zeros(4, bool))
     _check_segjoin(bk, pk, how, np.zeros(3, bool), np.zeros(4, bool))
+
+
+@pytest.mark.parametrize("how", HOWS)
+def test_segment_join_device_heavy_duplicates(how):
+    """Match runs longer than 127 rows: the emission's running count
+    takes more than one 7-bit digit of `bloom.prefix_sum`."""
+    rng = np.random.default_rng(5)
+    bk = rng.integers(0, 3, 700).astype(np.int64)
+    pk = rng.integers(0, 4, 300).astype(np.int64)
+    _check_segjoin(bk, pk, how, rng.random(700) < 0.9, None)
+
+
+@pytest.mark.parametrize("n", [64, 1000, 1 << 14])
+def test_prefix_sum_and_flatnonzero_match_numpy(n):
+    """The scatter- and cumsum-free device primitives vs numpy: exact
+    running sums of multi-digit values, and sized nonzero with its
+    zero fill."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(n)
+    vals = rng.integers(0, 1 << 16, n).astype(np.int32)  # sum < 2^31
+    np.testing.assert_array_equal(
+        np.asarray(bloom.prefix_sum(jnp.asarray(vals))), np.cumsum(vals))
+    ok = rng.random(n) < 0.3
+    for size in (n, n // 4):
+        want = np.zeros(size, np.int64)
+        hits = np.flatnonzero(ok)[:size]
+        want[:len(hits)] = hits
+        np.testing.assert_array_equal(
+            np.asarray(bloom.flatnonzero(jnp.asarray(ok), size)), want)
 
 
 def test_device_engine_empty_inputs_delegate():

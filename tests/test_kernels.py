@@ -73,3 +73,21 @@ def test_semijoin_duplicates_and_empty(rng):
     # all-masked build => nothing matches
     got = semi_mask(probe, build, np.zeros(len(build), bool))
     assert not got.any()
+
+
+@pytest.mark.parametrize("platform,want", [("cpu", True), ("tpu", False),
+                                           ("gpu", None)])
+def test_resolve_interpret_by_platform(monkeypatch, platform, want):
+    """Interpret mode defaults on for cpu only, off for tpu, and any
+    other platform raises; an explicit flag always wins."""
+    import jax
+
+    from repro.kernels import resolve_interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if want is None:
+        with pytest.raises(RuntimeError, match=platform):
+            resolve_interpret()
+    else:
+        assert resolve_interpret() is want
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False
