@@ -718,12 +718,11 @@ class _DeviceScan(VertexScan):
     (clipped to row 0, masked by an iota compare — no separate validity
     array to maintain).
 
-    Builds read the survivor ids; the jax engine routes them through
-    the bit-identical host mirror (`build_alive_np`) — off-TPU because
+    Builds read the survivor ids; off-TPU the jax engine routes them
+    through the bit-identical host mirror (`build_alive_np`), because
     XLA:CPU serializes the build's scatter (~1 µs/row — measured 30x
-    slower than the host mirror), on TPU because XLA's compiler takes
-    12-20 s per shape over it; the pallas engine's build kernel runs on
-    device from the same compacted ids."""
+    slower than the host mirror); on TPU the device build runs from the
+    same compacted ids."""
 
     def __init__(self, mask: np.ndarray, engine: "BloomEngine"):
         self._e = engine
@@ -985,9 +984,8 @@ class BloomEngine:
       benches, tests)."""
 
     backend = "base"
-    #: filter builds run through the bit-identical host mirror (set by
-    #: the jax engine: XLA's build scatter is serialized on CPU and slow
-    #: to compile on TPU)
+    #: device engines set True off-TPU: filter builds run through the
+    #: bit-identical host mirror (XLA:CPU serializes the build scatter)
     host_build = False
     #: device engines set True off-TPU: survivor compaction runs on host
     #: (XLA:CPU's sized-nonzero is scan-heavy; the mask is synced for the
@@ -1105,8 +1103,7 @@ class JaxEngine(BloomEngine):
     """jit'd `repro.core.bloom` ops over bucketed, survivor-compacted
     batches: device hash state per column is computed once
     (`EngineKeys.dev_hashed`), every probe is the hashed block-row
-    gather, and builds run through the host mirror (on TPU, and
-    off-TPU outside device-resident mode)."""
+    gather, and off-TPU builds run through the host mirror."""
 
     backend = "jax"
 
@@ -1117,15 +1114,14 @@ class JaxEngine(BloomEngine):
         if device_resident is None:
             device_resident = on_tpu
         self.device_resident = bool(device_resident)
-        # device-resident mode keeps compaction on device even off-TPU
-        # (the CI validation posture); otherwise off-TPU routes it
-        # through host flatnonzero. Builds run through the bit-identical
-        # host mirror off-TPU (unless device-resident) and always on
-        # TPU, where XLA's compiler takes 12-20 s per (keys, blocks)
-        # shape over the build scatter — the pallas engine's build
-        # kernel is the device build there
-        self.host_compact = not on_tpu and not self.device_resident
-        self.host_build = on_tpu or self.host_compact
+        # device-resident mode keeps builds and compaction on device even
+        # off-TPU (the CI validation posture); otherwise off-TPU routes
+        # both through the bit-identical host mirrors. On TPU, XLA's
+        # compiler takes 17-21 s per (keys, blocks) shape over the build
+        # scatter; the compile cache keeps it for later processes
+        host_side = not on_tpu and not self.device_resident
+        self.host_build = host_side
+        self.host_compact = host_side
 
     def keys(self, values):
         lo, hi = hashing.key_halves(np.asarray(values))

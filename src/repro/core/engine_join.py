@@ -306,11 +306,9 @@ class _HashMapJoinEngine(JoinEngine):
     reference. Duplicates are detected from the map occupancy (equal
     keys dedup into one slot) and fall back to the host engine."""
 
-    #: builds above this size run on host: the serial-insert build is
-    #: only worth jit/kernel dispatch below it off-TPU, and its four
-    #: tables (capacity 2x the next power of two) stay within the
-    #: kernel's VMEM budget on TPU
-    device_max_build = 1 << 21
+    #: builds above this size fall back to host (the serial-insert build
+    #: is only worth jit/kernel dispatch below it off-TPU)
+    device_max_build = 1 << 22
 
     #: device-resident data plane (DESIGN.md §15): route every join
     #: through the sorted-segment device path
@@ -408,6 +406,9 @@ class PallasJoinEngine(_HashMapJoinEngine):
     primitive, not a Pallas kernel)."""
 
     backend = "pallas"
+    #: the kernels keep all four tables (capacity 2x the next power of
+    #: two) in VMEM: 64 MiB at this size, within `VMEM_TABLE_MAX`
+    device_max_build = 1 << 21
 
     def __init__(self, interpret: Optional[bool] = None,
                  device_resident: Optional[bool] = None):

@@ -133,10 +133,10 @@ def test_track_restores_previous_context():
 def _engine(backend):
     if backend == "jax-tpu":
         # the routing JaxEngine takes on a TPU: device-resident scans,
-        # filter builds on the host mirror
+        # device compaction and device filter builds
         from repro.core.engine_bloom import JaxEngine
         eng = JaxEngine(device_resident=True)
-        eng.host_build = True
+        assert not (eng.host_build or eng.host_compact)
         return eng
     return get_engine(backend)
 

@@ -346,3 +346,40 @@ def test_mesh_exchange_ships_validity_planes():
                 np.testing.assert_array_equal(gp, ep, err_msg=how)
     print("mesh exchange validity planes OK")
     """)
+
+
+def test_broadcast_join_runs_each_shard_on_its_device():
+    """A device-backed broadcast join with a device-resident local
+    engine: the exchange places shard blocks on every mesh device, each
+    shard's local join runs under its own device's default-device scope
+    (`MeshExchange.placement`, `DistStats.local_devices`), and the
+    gathered indices are md5-equal to the single-host join."""
+    _run("""
+    import hashlib
+    from repro.core.engine_join import NumpyJoinEngine, get_join_engine
+    from repro.core.engine_join_dist import DistributedJoinEngine
+
+    def md5(b, p):
+        h = hashlib.md5()
+        for a in (b, p):
+            h.update(np.asarray(a, np.int64).tobytes())
+        return h.hexdigest()
+
+    rng = np.random.default_rng(3)
+    bk = rng.integers(0, 500, 300).astype(np.int64)
+    pk = rng.integers(0, 600, 40000).astype(np.int64)
+    host = NumpyJoinEngine()
+    for p in (4, 8):
+        eng = DistributedJoinEngine(nshards=p, local_backend="jax",
+                                    device=True)
+        eng.local = get_join_engine("jax", device_resident=True)
+        for how in ("inner", "left", "semi", "anti"):
+            got = eng.join_indices(bk, pk, how=how)
+            assert md5(*got) == md5(*host.join_indices(bk, pk, how)), how
+        assert {j.strategy for j in eng.stats.joins} == {"broadcast"}
+        mesh = {d.id for d in eng.exchange.devices}
+        assert len(mesh) == p, mesh
+        assert eng.exchange.placement == mesh, eng.exchange.placement
+        assert eng.stats.local_devices == mesh, eng.stats.local_devices
+    print("broadcast join placement OK")
+    """)
