@@ -1,31 +1,51 @@
-"""Host<->device traffic accounting for the device-resident data plane.
+"""Host<->device traffic accounting and host spans for one query run.
 
 The device-resident refactor (DESIGN.md section 15) keeps transfer and
 join intermediates on the accelerator; the host only schedules.  Its
-claim — "fewer host<->device round trips" — must be measurable, so every
-place the engines intentionally cross the boundary calls one of the
-counters here.  A query run wraps itself in :func:`track`; with no
-active context every counter is a no-op, so library code can call them
-unconditionally.
+claim — "fewer host<->device round trips" — must be measurable, so the
+boundary is crossed only through the functions here.  A query run wraps
+itself in :func:`track`; with no active context every counter is a
+no-op, so library code can call them unconditionally.
 
-Counted events:
+Crossings, each counted and timed as it happens:
 
-``h2d``  host -> device uploads (filter words, key halves, validity).
-``d2h``  device -> host syncs.  A scalar sync (``int(x.sum())``) counts
-         as one sync of ``SCALAR_BYTES``; an array sync counts its
-         nbytes.  Both block the host on device completion, so the
-         *sync count* (not bytes) is what the round-trip gate watches.
+``to_device``  host -> device upload (filter words, key halves,
+               validity); the only sanctioned h2d path.  Runs inside a
+               ``device.upload`` span.
+``to_host``    device -> host array sync, counted at its nbytes.
+``scalar``     device -> host scalar sync (``int(x.sum())``), counted
+               as ``SCALAR_BYTES``.
+               These two are the only sanctioned d2h paths: each runs
+               inside a ``device.wait`` span, so the sync is counted
+               where the host blocks on the device, and a bare
+               ``np.asarray`` of a device array is a fault.  The *sync
+               count* (not bytes) is what the round-trip gate watches.
 
-The counters are thread-local: concurrent queries through
-``repro.serve`` each see only their own traffic.  Nested contexts
-attribute to the innermost one; the executor merges subquery stats
-upward explicitly (mirroring how ``ExecStats.subqueries`` works).
+:func:`span` names a stretch of host work: it is a
+``jax.profiler.TraceAnnotation`` ``pt.<name>`` (under a profiler session
+it lands in the trace on the running thread, on the clock of the
+device's op events) and, inside :func:`track`, a (count, nanoseconds)
+entry of ``DeviceStats.spans`` read with ``time.perf_counter_ns``.
+Spans name query phases and layer boundaries, never per-row or
+per-block work.
+
+The record is thread-local: concurrent queries through ``repro.serve``
+each see only their own traffic.  Nested contexts attribute to the
+innermost one; the executor merges subquery stats upward explicitly
+(mirroring how ``ExecStats.subqueries`` works).
 """
 from __future__ import annotations
 
 import threading
+import time
 from contextlib import contextmanager
-from dataclasses import dataclass
+from dataclasses import dataclass, field
+from typing import Dict, List
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.profiler import TraceAnnotation
 
 SCALAR_BYTES = 8
 
@@ -39,7 +59,17 @@ class DeviceStats:
     d2h_syncs: int = 0
     d2h_bytes: int = 0
     fused_calls: int = 0          # fused multi-filter probe invocations
-    device_compactions: int = 0   # survivor compactions done on device
+    # span name -> [count, nanoseconds] (`span`; `serve.queued` is set
+    # by the server)
+    spans: Dict[str, List[int]] = field(default_factory=dict)
+
+    def add_span(self, name: str, ns: int, count: int = 1) -> None:
+        rec = self.spans.get(name)
+        if rec is None:
+            self.spans[name] = [count, ns]
+        else:
+            rec[0] += count
+            rec[1] += ns
 
     def round_trips(self) -> int:
         return self.h2d_syncs + self.d2h_syncs
@@ -50,7 +80,8 @@ class DeviceStats:
         self.d2h_syncs += other.d2h_syncs
         self.d2h_bytes += other.d2h_bytes
         self.fused_calls += other.fused_calls
-        self.device_compactions += other.device_compactions
+        for name, (count, ns) in other.spans.items():
+            self.add_span(name, ns, count)
 
     def report(self) -> dict:
         return {
@@ -60,8 +91,12 @@ class DeviceStats:
             "d2h_bytes": self.d2h_bytes,
             "round_trips": self.round_trips(),
             "fused_calls": self.fused_calls,
-            "device_compactions": self.device_compactions,
         }
+
+    def span_report(self) -> dict:
+        """{name: [count, seconds]}."""
+        return {name: [count, ns / 1e9]
+                for name, (count, ns) in sorted(self.spans.items())}
 
 
 _tls = threading.local()
@@ -82,14 +117,45 @@ def track(stats: DeviceStats):
         _tls.stats = prev
 
 
-def count_h2d(nbytes: int = SCALAR_BYTES) -> None:
+class span:
+    """``with span("scan"):`` — a ``pt.scan`` profiler annotation, and
+    inside :func:`track` one count and the elapsed nanoseconds added to
+    the active record's ``spans["scan"]``; ``.seconds`` is this span's
+    own time (0.0 outside :func:`track`)."""
+
+    __slots__ = ("name", "ns", "_ann", "_stats", "_t0")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.ns = 0
+        self._ann = TraceAnnotation("pt." + name)
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        self._stats = active()
+        if self._stats is not None:
+            self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._stats is not None:
+            self.ns = time.perf_counter_ns() - self._t0
+            self._stats.add_span(self.name, self.ns)
+        self._ann.__exit__(*exc)
+
+    @property
+    def seconds(self) -> float:
+        return self.ns / 1e9
+
+
+def _count_h2d(nbytes: int) -> None:
     s = active()
     if s is not None:
         s.h2d_syncs += 1
         s.h2d_bytes += int(nbytes)
 
 
-def count_d2h(nbytes: int = SCALAR_BYTES) -> None:
+def _count_d2h(nbytes: int) -> None:
     s = active()
     if s is not None:
         s.d2h_syncs += 1
@@ -102,34 +168,36 @@ def count_fused() -> None:
         s.fused_calls += 1
 
 
-def count_compaction() -> None:
-    s = active()
-    if s is not None:
-        s.device_compactions += 1
-
-
 def scalar(x) -> int:
-    """``int(x)`` for a device scalar, counted as one d2h sync."""
-    count_d2h(SCALAR_BYTES)
-    return int(x)
+    """``int(x)`` for a device scalar: one d2h sync, waited for inside
+    a ``device.wait`` span."""
+    with span("device.wait"):
+        _count_d2h(SCALAR_BYTES)
+        return int(x)
 
 
 def to_host(a):
-    """``np.asarray`` with d2h accounting (free for host arrays)."""
-    import numpy as np
-
+    """``np.asarray``; a device array is one d2h sync, waited for inside
+    a ``device.wait`` span (host arrays are free)."""
     if isinstance(a, np.ndarray) or not hasattr(a, "__array__"):
         return np.asarray(a)
-    out = np.asarray(a)
-    count_d2h(out.nbytes)
+    with span("device.wait"):
+        out = np.asarray(a)
+    _count_d2h(out.nbytes)
     return out
 
 
-def to_device(a):
-    """``jnp.asarray`` with h2d accounting (free for device arrays)."""
-    import jax.numpy as jnp
-    import numpy as np
+def to_device(a, sharding=None):
+    """``jnp.asarray`` (``jax.device_put`` onto `sharding` when given);
+    a host array is one h2d upload inside a ``device.upload`` span
+    (device arrays are free)."""
+    if not isinstance(a, np.ndarray):
+        return _put(a, sharding)
+    with span("device.upload"):
+        _count_h2d(a.nbytes)
+        return _put(a, sharding)
 
-    if isinstance(a, np.ndarray):
-        count_h2d(a.nbytes)
-    return jnp.asarray(a)
+
+def _put(a, sharding):
+    return jnp.asarray(a) if sharding is None \
+        else jax.device_put(a, sharding)

@@ -108,13 +108,14 @@ class EngineKeys:
         keys or from the device backends' uint32 halves — bit-identical
         either way)."""
         if self.h is None:
-            if alive is not None and alive.size * 2 < self.n:
-                return self._hash_subset(alive)
-            if self.raw is not None:
-                self.h, self.g1, self.g2 = _hash_host(self.raw)
-            else:
-                self.h, self.g1, self.g2 = _hash_host_halves(self.lo,
-                                                             self.hi)
+            with device_plane.span("transfer.hash"):
+                if alive is not None and alive.size * 2 < self.n:
+                    return self._hash_subset(alive)
+                if self.raw is not None:
+                    self.h, self.g1, self.g2 = _hash_host(self.raw)
+                else:
+                    self.h, self.g1, self.g2 = _hash_host_halves(self.lo,
+                                                                 self.hi)
         if alive is None:
             return self.h, self.g1, self.g2
         return (self.h.take(alive), self.g1.take(alive),
@@ -759,17 +760,14 @@ class _DeviceScan(VertexScan):
                 counts.append(0)
                 continue
             rows += self._count
-            if isinstance(words, np.ndarray):
-                device_plane.count_h2d(words.nbytes)
+            words = device_plane.to_device(words)
             ok = self._e.probe_idx(words, ek, self._idx, self._count,
                                    self._n)
             if self._e.host_compact:
                 # off-TPU: XLA's sized-nonzero is O(n) scan-heavy and the
                 # count sync materializes the mask anyway — compact the
                 # tiny survivor-id array on host
-                okh = np.asarray(ok)
-                device_plane.count_d2h(okh.nbytes)
-                live = np.flatnonzero(okh)
+                live = np.flatnonzero(device_plane.to_host(ok))
                 count = int(live.size)
                 if count != self._count:
                     self._bucket = self._e.bucket(count)
@@ -781,7 +779,6 @@ class _DeviceScan(VertexScan):
                 if count != self._count:
                     self._bucket = self._e.bucket(count)
                     self._idx = _compact(ok, self._idx, self._bucket)
-                    device_plane.count_compaction()
             if count != self._count:
                 self._count = count
                 self._mask_out = None
@@ -796,17 +793,13 @@ class _DeviceScan(VertexScan):
         if self._count == 0:
             self.live_after = [0] * len(incoming)
             return 0
-        words_dev = []
-        for w, _ in incoming:
-            if isinstance(w, np.ndarray):
-                device_plane.count_h2d(w.nbytes)
-            words_dev.append(jnp.asarray(w))
+        words_dev = tuple(device_plane.to_device(w) for w, _ in incoming)
         idx, dcounts = self._e.fused_probe_idx(
-            tuple(words_dev), [ek for _, ek in incoming], self._idx,
+            words_dev, [ek for _, ek in incoming], self._idx,
             self._count, self._n)
         device_plane.count_fused()
-        host_counts = np.asarray(dcounts)   # the vertex's ONE d2h sync
-        device_plane.count_d2h(host_counts.nbytes)
+        # the vertex's ONE d2h sync
+        host_counts = device_plane.to_host(dcounts)
         self.live_after = [int(c) for c in host_counts]
         # rows-probed accounting matches the sequential path: filter f
         # "sees" the rows still live when it runs (the device does
@@ -822,7 +815,6 @@ class _DeviceScan(VertexScan):
             self._count = new_count
             self._mask_out = None
             self._hidx = None
-            device_plane.count_compaction()
         return rows
 
     def probe_range(self, raw, lo, hi, ek=None):
@@ -873,7 +865,6 @@ class _DeviceScan(VertexScan):
             self._count = new_count
             self._mask_out = None
             self._hidx = None
-            device_plane.count_compaction()
         return rows
 
     def key_range(self, raw, ek=None, valid=None):
@@ -888,15 +879,13 @@ class _DeviceScan(VertexScan):
                  if self._idx is None else
                  _minmax_gather(dlo, dhi, self._idx, self._count))
         else:
-            v = _pad(np.asarray(valid, bool), b, False)
-            device_plane.count_h2d(v.nbytes)
-            v = jnp.asarray(v)
+            v = device_plane.to_device(_pad(np.asarray(valid, bool), b,
+                                            False))
             q = (_minmax_count_valid(dlo, dhi, self._count, v)
                  if self._idx is None else
                  _minmax_gather_valid(dlo, dhi, self._idx, self._count,
                                       v))
-        qh = np.asarray(q)
-        device_plane.count_d2h(qh.nbytes)
+        qh = device_plane.to_host(q)
         lo = _val_from_halves(qh[0], qh[1])
         hi = _val_from_halves(qh[2], qh[3])
         if lo > hi:             # every live row was invalid
@@ -927,8 +916,7 @@ class _DeviceScan(VertexScan):
             return None
         if not isinstance(self._idx, np.ndarray):
             if self._hidx is None:
-                out = np.asarray(self._idx)
-                device_plane.count_d2h(out.nbytes)
+                out = device_plane.to_host(self._idx)
                 self._hidx = out[: self._count].astype(np.int64)
             return self._hidx
         return np.asarray(self._idx)[: self._count].astype(np.int64)
@@ -1087,9 +1075,10 @@ class NumpyEngine(BloomEngine):
     backend = "numpy"
 
     def keys(self, values):
-        keys = np.asarray(values).astype(np.int64, copy=False)
-        if not keys.flags.c_contiguous:
-            keys = np.ascontiguousarray(keys)
+        with device_plane.span("transfer.keys"):
+            keys = np.asarray(values).astype(np.int64, copy=False)
+            if not keys.flags.c_contiguous:
+                keys = np.ascontiguousarray(keys)
         # lazy: EngineKeys.hga hashes the full column once on first
         # mostly-alive use, or just the survivor subset when earlier
         # filters already shrank the working set
@@ -1124,7 +1113,8 @@ class JaxEngine(BloomEngine):
         self.host_compact = host_side
 
     def keys(self, values):
-        lo, hi = hashing.key_halves(np.asarray(values))
+        with device_plane.span("transfer.keys"):
+            lo, hi = hashing.key_halves(np.asarray(values))
         return EngineKeys(len(lo), lo=lo, hi=hi)
 
     def begin(self, mask):
@@ -1183,7 +1173,8 @@ class PallasEngine(BloomEngine):
         self.host_compact = not on_tpu and not self.device_resident
 
     def keys(self, values):
-        lo, hi = hashing.key_halves(np.asarray(values))
+        with device_plane.span("transfer.keys"):
+            lo, hi = hashing.key_halves(np.asarray(values))
         return EngineKeys(len(lo), lo=lo, hi=hi)
 
     def begin(self, mask):

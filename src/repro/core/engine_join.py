@@ -515,7 +515,7 @@ def _compose(sel: Optional[np.ndarray], idx: np.ndarray,
         return sel[idx_host]
     if not host_sel and host_idx:
         from repro.core import device_plane
-        device_plane.count_h2d(idx.nbytes)
+        idx = device_plane.to_device(idx)
     return sel[idx]
 
 
@@ -553,7 +553,7 @@ def _compose_nullable(sel: Optional[np.ndarray], idx: np.ndarray,
     if len(sel) == 0:
         return jnp.full(len(idx), -1, jnp.int32)
     if host_idx:
-        device_plane.count_h2d(idx.nbytes)
+        idx = device_plane.to_device(idx)
     neg = idx < 0
     out = sel[jnp.where(neg, 0, idx)]
     return jnp.where(neg, jnp.int32(-1), out)

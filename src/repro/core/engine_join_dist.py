@@ -225,11 +225,8 @@ class MeshExchange:
         return _bucket(n, floor=8)
 
     def _put(self, arr: np.ndarray):
-        import jax
-
         from repro.core import device_plane
-        device_plane.count_h2d(arr.nbytes)
-        out = jax.device_put(arr, self._sharding)
+        out = device_plane.to_device(arr, self._sharding)
         self.placement.update(d.id for d in out.devices())
         return out
 

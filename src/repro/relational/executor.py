@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 import warnings
 from typing import (
     Callable, Dict, List, Mapping, Optional, Tuple, Union,
@@ -64,6 +63,7 @@ class JoinStat:
 @dataclasses.dataclass
 class ExecStats:
     strategy: str = ""
+    # this executor's own `scan`/`transfer`/`join` spans, in seconds
     phase_seconds: Dict[str, float] = dataclasses.field(default_factory=dict)
     transfer: Optional[TransferStats] = None
     joins: List[JoinStat] = dataclasses.field(default_factory=list)
@@ -188,6 +188,9 @@ class ExecStats:
             },
             "degraded": list(self.degraded),
             "device": self.device.report(),
+            # {name: [count, seconds]} of the host spans
+            # (`device_plane.span`), subqueries folded in
+            "spans": self.device.span_report(),
             "dist": None,
         }
         if self.dist is not None:
@@ -556,7 +559,7 @@ class Executor:
         in `stats.device` (subquery crossings are merged in where their
         stats are collected — `track` re-points the thread-local)."""
         stats = ExecStats(strategy=self.strategy.name)
-        with device_plane.track(stats.device):
+        with device_plane.track(stats.device), device_plane.span("query"):
             return self._execute_tracked(plan, ctx, stats)
 
     def _execute_tracked(self, plan: PlanNode,
@@ -578,9 +581,57 @@ class Executor:
                 hedge=self.config.hedge)
             stats.dist = self.join_engine.stats
 
-        # -- cache identity: canonical plan fingerprint (DESIGN §12) ----
-        t0 = time.perf_counter()
-        leaves = plan.leaves()
+        # -- phase 0: scan --------------------------------------------
+        with device_plane.span("scan") as scan:
+            leaves = plan.leaves()
+            fp, cat_sig, info, slot_key = self._cache_identity(plan)
+            # warm path: replay the post-transfer slot state
+            ent = None if slot_key is None \
+                else self.artifact_cache.get(slot_key)
+            if ent is not None:
+                cached_slots, transfer_snap = ent
+                # per-hit Slot copies: slot tables are immutable and
+                # shared, but Slot.keys is a lazily-growing dict the
+                # join phase mutates — each query gets its own
+                slots = {leaf.leaf_id: Slot(tbl, dict(keys))
+                         for leaf, (tbl, keys)
+                         in zip(leaves, cached_slots)}
+                stats.transfer = self._replay_transfer(transfer_snap)
+            else:
+                # leaves, with projection pushdown
+                from repro.relational.optimize import collect_columns
+                needed = set(info.needed) if info is not None \
+                    else collect_columns(plan)
+                vertices = {leaf.leaf_id:
+                            self._resolve_leaf(leaf, stats, needed)
+                            for leaf in leaves}
+        stats.phase_seconds["scan"] = scan.seconds
+
+        # -- phase 1: transfer (a slot-cache hit replayed it) -----------
+        if ent is not None:
+            stats.phase_seconds["transfer"] = 0.0
+        else:
+            with device_plane.span("transfer") as transfer:
+                slots = self._transfer(plan, leaves, vertices, needed,
+                                       (fp, cat_sig, info, slot_key),
+                                       stats)
+            stats.phase_seconds["transfer"] = transfer.seconds
+        self._arm_reorder(leaves, stats.transfer)
+
+        # -- phase 2: join ---------------------------------------------
+        with device_plane.span("join") as join:
+            self._phase = "join"
+            if ctx is not None:
+                ctx.check("join")
+            result = self._exec(plan, slots, stats)
+        stats.phase_seconds["join"] = join.seconds
+        stats.result_rows = len(result)
+        return result, stats
+
+    def _cache_identity(self, plan: PlanNode) -> tuple:
+        """(plan fingerprint, catalog signature, plan-cache entry,
+        slot-cache key) of `plan` (DESIGN §12), each None where no cache
+        or history asks for it."""
         fp = cat_sig = info = slot_key = None
         if (self.plan_cache is not None
                 or self.artifact_cache is not None
@@ -595,43 +646,15 @@ class Executor:
                     ssig = self.strategy.cache_signature()
                     if ssig is not None:
                         slot_key = ("slots", fp, cat_sig, ssig)
+        return fp, cat_sig, info, slot_key
 
-        # -- warm path: replay the post-transfer slot state -------------
-        if slot_key is not None:
-            ent = self.artifact_cache.get(slot_key)
-            if ent is not None:
-                cached_slots, transfer_snap = ent
-                # per-hit Slot copies: slot tables are immutable and
-                # shared, but Slot.keys is a lazily-growing dict the
-                # join phase mutates — each query gets its own
-                slots = {leaf.leaf_id: Slot(tbl, dict(keys))
-                         for leaf, (tbl, keys)
-                         in zip(leaves, cached_slots)}
-                stats.transfer = self._replay_transfer(transfer_snap)
-                stats.phase_seconds["scan"] = time.perf_counter() - t0
-                stats.phase_seconds["transfer"] = 0.0
-                self._arm_reorder(leaves, stats.transfer)
-                t0 = time.perf_counter()
-                self._phase = "join"
-                if ctx is not None:
-                    ctx.check("join")
-                result = self._exec(plan, slots, stats)
-                stats.phase_seconds["join"] = time.perf_counter() - t0
-                stats.result_rows = len(result)
-                return result, stats
-
-        # -- phase 0: leaves (with projection pushdown) ------------------
-        from repro.relational.optimize import collect_columns
-        needed = set(info.needed) if info is not None \
-            else collect_columns(plan)
-        vertices: Dict[int, Vertex] = {}
-        for leaf in leaves:
-            vertices[leaf.leaf_id] = self._resolve_leaf(leaf, stats,
-                                                        needed)
-        stats.phase_seconds["scan"] = time.perf_counter() - t0
-
-        # -- phase 1: transfer -----------------------------------------
-        t0 = time.perf_counter()
+    def _transfer(self, plan: PlanNode, leaves, vertices: Dict[int, Vertex],
+                  needed: set, identity: tuple,
+                  stats: ExecStats) -> Dict[int, Slot]:
+        """The transfer phase: the strategy pre-filters `vertices`, then
+        each is compacted to its survivors. Returns the join slots."""
+        fp, cat_sig, info, slot_key = identity
+        ctx = self._ctx
         self._phase = "transfer"
         if ctx is not None:
             ctx.check("transfer")
@@ -667,33 +690,23 @@ class Executor:
         # compact each vertex once; the transfer phase's composite keys
         # are compacted alongside and seed the join runtime's key cache
         slots: Dict[int, Slot] = {}
-        for lid, v in vertices.items():
-            idx = np.flatnonzero(v.mask)
-            full = idx.size == len(v.mask)
-            table = v.table if full else v.table.gather(idx)
-            # seed only keys whose encoding cannot flip under row
-            # filtering (ops.stable_key_encoding) — an unstable 2-col
-            # key is recomputed on the compacted table instead, exactly
-            # as the eager oracle would
-            keys = {cols: (raw if full else raw[idx])
-                    for cols, raw in v.raw_keys.items()
-                    if ops.stable_key_encoding(v.table, cols)}
-            slots[lid] = Slot(table, keys)
+        with device_plane.span("transfer.compact"):
+            for lid, v in vertices.items():
+                idx = np.flatnonzero(v.mask)
+                full = idx.size == len(v.mask)
+                table = v.table if full else v.table.gather(idx)
+                # seed only keys whose encoding cannot flip under row
+                # filtering (ops.stable_key_encoding) — an unstable
+                # 2-col key is recomputed on the compacted table
+                # instead, exactly as the eager oracle would
+                keys = {cols: (raw if full else raw[idx])
+                        for cols, raw in v.raw_keys.items()
+                        if ops.stable_key_encoding(v.table, cols)}
+                slots[lid] = Slot(table, keys)
         if slot_key is not None:
             self._store_slots(slot_key, leaves, slots, stats.transfer,
                               cat_sig)
-        stats.phase_seconds["transfer"] = time.perf_counter() - t0
-        self._arm_reorder(leaves, stats.transfer)
-
-        # -- phase 2: join ---------------------------------------------
-        t0 = time.perf_counter()
-        self._phase = "join"
-        if ctx is not None:
-            ctx.check("join")
-        result = self._exec(plan, slots, stats)
-        stats.phase_seconds["join"] = time.perf_counter() - t0
-        stats.result_rows = len(result)
-        return result, stats
+        return slots
 
     # -- runtime join ordering (DESIGN §14) -----------------------------
     def _reorder_active(self) -> bool:
@@ -757,7 +770,8 @@ class Executor:
                       needed: Optional[set] = None) -> Vertex:
         if isinstance(leaf, SubqueryScan):
             sub = self._sub_executor()
-            table, sub_stats = sub.execute(leaf.plan, ctx=self._ctx)
+            with device_plane.span("subquery"):
+                table, sub_stats = sub.execute(leaf.plan, ctx=self._ctx)
             stats.subqueries.append(sub_stats)
             stats.device.merge(sub_stats.device)
             table = Table(table.columns, leaf.alias)
@@ -828,34 +842,51 @@ class Executor:
 
     def _materialize(self, cur: JoinCursor, stats: ExecStats,
                      names: Optional[set] = None) -> Table:
-        avail = None
-        if names is not None:
-            avail = [n for n, _ in cur.cols if n in names]
-            if not avail and cur.cols:
-                # a value-free operator (e.g. bare count(*)) still needs
-                # the row count, which a zero-column Table loses
-                avail = [cur.cols[0][0]]
-        budget = self._mem_budget()
-        if budget is not None:
-            # pre-gather guard: estimate rows × row bytes before any
-            # allocation; exceeding the budget degrades instead of OOMs
-            est = stats.join_materialized_bytes + cur.gather_bytes(avail)
-            if est > budget:
-                raise ResourceExhausted(
-                    f"payload gather needs ~{est} bytes "
-                    f"(budget {budget})", phase="join",
-                    tag=self._ctx.tag if self._ctx else "")
-        if avail is not None:
-            table, nbytes = cur.materialize(avail)
-        else:
-            table, nbytes = cur.materialize()
-        stats.join_materialized_bytes += nbytes
-        return table
+        with device_plane.span("join.materialize"):
+            avail = None
+            if names is not None:
+                avail = [n for n, _ in cur.cols if n in names]
+                if not avail and cur.cols:
+                    # a value-free operator (e.g. bare count(*)) still
+                    # needs the row count, which a zero-column Table
+                    # loses
+                    avail = [cur.cols[0][0]]
+            budget = self._mem_budget()
+            if budget is not None:
+                # pre-gather guard: estimate rows × row bytes before
+                # any allocation; exceeding the budget degrades instead
+                # of OOMs
+                est = (stats.join_materialized_bytes
+                       + cur.gather_bytes(avail))
+                if est > budget:
+                    raise ResourceExhausted(
+                        f"payload gather needs ~{est} bytes "
+                        f"(budget {budget})", phase="join",
+                        tag=self._ctx.tag if self._ctx else "")
+            if avail is not None:
+                table, nbytes = cur.materialize(avail)
+            else:
+                table, nbytes = cur.materialize()
+            stats.join_materialized_bytes += nbytes
+            return table
 
     @staticmethod
     def _as_cursor(out: Union[Table, JoinCursor]) -> JoinCursor:
         return out if isinstance(out, JoinCursor) \
             else JoinCursor.from_table(out)
+
+    def _aggregate(self, t: Union[Table, JoinCursor], node: GroupBy,
+                   stats: ExecStats) -> Table:
+        if not isinstance(t, JoinCursor):
+            return ops.group_aggregate(t, node.keys, node.aggs)
+        out = self._group_cursor(t, node, stats)
+        if out is None:
+            # having filters aggregate *outputs*, so only the group keys
+            # and agg inputs need values
+            needed = set(node.keys) | {ic for _, _, ic in node.aggs if ic}
+            t = self._materialize(t, stats, needed)
+            out = ops.group_aggregate(t, node.keys, node.aggs)
+        return out
 
     def _group_cursor(self, cur: JoinCursor, node: GroupBy,
                       stats: ExecStats) -> Optional[Table]:
@@ -991,7 +1022,8 @@ class Executor:
         if isinstance(node, Bind):
             t = self._exec(node.child, slots, stats)
             sub = self._sub_executor()
-            sub_t, sub_stats = sub.execute(node.subplan, ctx=self._ctx)
+            with device_plane.span("subquery"):
+                sub_t, sub_stats = sub.execute(node.subplan, ctx=self._ctx)
             stats.subqueries.append(sub_stats)
             stats.device.merge(sub_stats.device)
             assert len(sub_t) == 1, "Bind subplan must yield one row"
@@ -1007,17 +1039,8 @@ class Executor:
 
         if isinstance(node, GroupBy):
             t = self._exec_node(node.child, slots, stats)
-            if isinstance(t, JoinCursor):
-                out = self._group_cursor(t, node, stats)
-                if out is None:
-                    # having filters aggregate *outputs*, so only the
-                    # group keys and agg inputs need values
-                    needed = set(node.keys) | {ic for _, _, ic
-                                               in node.aggs if ic}
-                    t = self._materialize(t, stats, needed)
-                    out = ops.group_aggregate(t, node.keys, node.aggs)
-            else:
-                out = ops.group_aggregate(t, node.keys, node.aggs)
+            with device_plane.span("join.aggregate"):
+                out = self._aggregate(t, node, stats)
             if node.having is not None:
                 out = out.compact(node.having(out).mask(len(out)))
             return out

@@ -50,7 +50,7 @@ from typing import Dict, List, Mapping, Optional, Tuple
 
 import numpy as np
 
-from repro.core import faultinject, recovery
+from repro.core import device_plane, faultinject, recovery
 from repro.core.artifact_cache import ArtifactCache
 from repro.core.errors import (
     BackendError, DeadlineExceeded, QueryCancelled, QueryContext,
@@ -265,7 +265,7 @@ class ServerMetrics:
 
 class _Request:
     __slots__ = ("plan", "strategy", "strategy_kw", "tag", "future",
-                 "ctx")
+                 "ctx", "submitted_ns")
 
     def __init__(self, plan, strategy, strategy_kw, tag, future, ctx):
         self.plan = plan
@@ -274,6 +274,9 @@ class _Request:
         self.tag = tag
         self.future = future
         self.ctx = ctx
+        # start of the query's `serve.queued` span, ended at worker
+        # pickup (a span cannot be backdated, so it is kept in memory)
+        self.submitted_ns = time.perf_counter_ns()
 
 
 class QueryServer:
@@ -383,6 +386,7 @@ class QueryServer:
             if req is None:             # shutdown sentinel
                 self._queue.task_done()
                 return
+            queued_ns = time.perf_counter_ns() - req.submitted_ns
             if not req.future.set_running_or_notify_cancel():
                 self._queue.task_done()
                 continue
@@ -403,7 +407,8 @@ class QueryServer:
                 return
             t0 = time.perf_counter()
             try:
-                result = self._execute(req)
+                with device_plane.span("serve.execute"):
+                    result = self._execute(req)
             except BaseException as e:   # noqa: BLE001 — relayed to caller
                 # one failing query errors its own Future; the worker
                 # thread survives to serve the next request
@@ -412,6 +417,7 @@ class QueryServer:
                                          error=e)
                 req.future.set_exception(e)
             else:
+                result[1].device.add_span("serve.queued", queued_ns)
                 self.metrics.record_done(req.tag,
                                          time.perf_counter() - t0,
                                          result[1].report())
