@@ -59,6 +59,12 @@ class DeviceStats:
     d2h_syncs: int = 0
     d2h_bytes: int = 0
     fused_calls: int = 0          # fused multi-filter probe invocations
+    # survivor compactions after a fused probe: how many, the slots they
+    # searched (the survivors' buckets) and the probe widths a
+    # compaction over the whole probe would have searched
+    compact_calls: int = 0
+    compact_slots: int = 0
+    compact_width: int = 0
     # span name -> [count, nanoseconds] (`span`; `serve.queued` is set
     # by the server)
     spans: Dict[str, List[int]] = field(default_factory=dict)
@@ -80,6 +86,9 @@ class DeviceStats:
         self.d2h_syncs += other.d2h_syncs
         self.d2h_bytes += other.d2h_bytes
         self.fused_calls += other.fused_calls
+        self.compact_calls += other.compact_calls
+        self.compact_slots += other.compact_slots
+        self.compact_width += other.compact_width
         for name, (count, ns) in other.spans.items():
             self.add_span(name, ns, count)
 
@@ -91,6 +100,9 @@ class DeviceStats:
             "d2h_bytes": self.d2h_bytes,
             "round_trips": self.round_trips(),
             "fused_calls": self.fused_calls,
+            "compact_calls": self.compact_calls,
+            "compact_slots": self.compact_slots,
+            "compact_width": self.compact_width,
         }
 
     def span_report(self) -> dict:
@@ -166,6 +178,14 @@ def count_fused() -> None:
     s = active()
     if s is not None:
         s.fused_calls += 1
+
+
+def count_compact(size: int, width: int) -> None:
+    s = active()
+    if s is not None:
+        s.compact_calls += 1
+        s.compact_slots += int(size)
+        s.compact_width += int(width)
 
 
 def scalar(x) -> int:

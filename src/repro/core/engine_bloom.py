@@ -389,8 +389,9 @@ def _compact(ok, idx, bucket: int):
 # --------------------------------------------------------------------------
 # fused device probe + range-cut + min-max (the device-resident data plane,
 # DESIGN.md §15): every incoming filter of a vertex is applied in one jit
-# graph ending in a device compaction, so the host syncs exactly one small
-# counts vector per vertex instead of one mask per filter
+# graph that returns the survivor mask and per-filter live counts, so the
+# host syncs exactly one small counts vector per vertex instead of one mask
+# per filter; the survivors are then compacted into their own bucket
 # --------------------------------------------------------------------------
 
 
@@ -413,8 +414,7 @@ def _fused_and(words, hs, g1s, g2s, ok, k):
 def _fused_probe_count(words, hs, g1s, g2s, count, k):
     n = hs[0].shape[0]
     ok = jnp.arange(n, dtype=jnp.int32) < count
-    ok, counts = _fused_and(words, hs, g1s, g2s, ok, k)
-    return bloom.flatnonzero(ok, n), counts
+    return _fused_and(words, hs, g1s, g2s, ok, k)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -424,8 +424,7 @@ def _fused_probe_gather(words, hs, g1s, g2s, idx, count, k):
     hg = tuple(h[idx] for h in hs)
     g1g = tuple(g[idx] for g in g1s)
     g2g = tuple(g[idx] for g in g2s)
-    ok, counts = _fused_and(words, hg, g1g, g2g, ok, k)
-    return idx[bloom.flatnonzero(ok, n)], counts
+    return _fused_and(words, hg, g1g, g2g, ok, k)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -434,8 +433,7 @@ def _fused_pallas_count(words, los, his, count, k, interpret):
     cum = _k.multi_probe_pallas(words, los, his, k=k, interpret=interpret)
     n = los[0].shape[0]
     cum = cum & (jnp.arange(n, dtype=jnp.int32) < count)[None, :]
-    counts = jnp.sum(cum, axis=1, dtype=jnp.int32)
-    return bloom.flatnonzero(cum[-1], n), counts
+    return cum[-1], jnp.sum(cum, axis=1, dtype=jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -446,8 +444,22 @@ def _fused_pallas_gather(words, los, his, idx, count, k, interpret):
     cum = _k.multi_probe_pallas(words, los, his, k=k, interpret=interpret)
     n = idx.shape[0]
     cum = cum & (jnp.arange(n, dtype=jnp.int32) < count)[None, :]
-    counts = jnp.sum(cum, axis=1, dtype=jnp.int32)
-    return idx[bloom.flatnonzero(cum[-1], n)], counts
+    return cum[-1], jnp.sum(cum, axis=1, dtype=jnp.int32)
+
+
+# `_compact` under the names of the Pallas probe programs it completes, so
+# a trace reader that selects `jit__fused_pallas_count*` /
+# `jit__fused_pallas_gather*` times each probe with its compaction
+
+
+@functools.partial(jax.jit, static_argnames=("size",))
+def _fused_pallas_count_compact(ok, size):
+    return bloom.flatnonzero(ok, size)
+
+
+@functools.partial(jax.jit, static_argnames=("size",))
+def _fused_pallas_gather_compact(ok, idx, size):
+    return idx[bloom.flatnonzero(ok, size)]
 
 
 def _bound_halves(v) -> Tuple[np.uint32, np.uint32, np.uint32]:
@@ -788,13 +800,14 @@ class _DeviceScan(VertexScan):
 
     def _probe_fused(self, incoming):
         """Device-resident probe: one jit graph applies every incoming
-        filter and compacts survivors on device; the host syncs a single
-        per-filter counts vector for the whole vertex."""
+        filter; the host syncs a single per-filter counts vector for the
+        whole vertex. Only if a filter removed rows are the survivors
+        then compacted on device, into their own bucket's slots."""
         if self._count == 0:
             self.live_after = [0] * len(incoming)
             return 0
         words_dev = tuple(device_plane.to_device(w) for w, _ in incoming)
-        idx, dcounts = self._e.fused_probe_idx(
+        ok, dcounts = self._e.fused_probe_idx(
             words_dev, [ek for _, ek in incoming], self._idx,
             self._count, self._n)
         device_plane.count_fused()
@@ -807,11 +820,10 @@ class _DeviceScan(VertexScan):
         rows = self._count + int(host_counts[:-1].sum())
         new_count = int(host_counts[-1])
         if new_count != self._count:
-            new_bucket = self._e.bucket(new_count)
-            if new_bucket != self._bucket:
-                idx = idx[:new_bucket]      # survivors are front-packed
-                self._bucket = new_bucket
-            self._idx = idx
+            size = self._e.bucket(new_count)
+            device_plane.count_compact(size, ok.shape[0])
+            self._idx = self._e.fused_compact(ok, self._idx, size)
+            self._bucket = size
             self._count = new_count
             self._mask_out = None
             self._hidx = None
@@ -996,10 +1008,17 @@ class BloomEngine:
         raise NotImplementedError
 
     def fused_probe_idx(self, words, eks, idx, count: int, n: int):
-        """One device pass over every incoming filter: returns (packed
-        survivor ids, device int32 live-count-after-each-filter vector)
-        — the caller syncs the counts once per vertex."""
+        """One device pass over every incoming filter: returns (device
+        bool survivor mask over the `idx` width, device int32
+        live-count-after-each-filter vector) — the caller syncs the
+        counts once per vertex."""
         raise NotImplementedError
+
+    def fused_compact(self, ok, idx, size: int):
+        """The survivor row ids of a `fused_probe_idx` mask (`idx` as
+        passed to it) in `size` slots, front-packed and zero-filled: the
+        search runs over `size` queries, not over the mask's width."""
+        return _compact(ok, idx, size)
 
     def build_idx(self, ek: "EngineKeys", idx, count: int, n: int,
                   nblocks: int, valid: Optional[np.ndarray] = None):
@@ -1198,6 +1217,11 @@ class PallasEngine(BloomEngine):
                                        self.interpret)
         return _fused_pallas_gather(words, los, his, idx, count, self.k,
                                     self.interpret)
+
+    def fused_compact(self, ok, idx, size):
+        if idx is None:
+            return _fused_pallas_count_compact(ok, size)
+        return _fused_pallas_gather_compact(ok, idx, size)
 
     def build_idx(self, ek, idx, count, n, nblocks, valid=None):
         lo, hi = ek.dev(self.bucket(n))

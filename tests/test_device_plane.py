@@ -374,6 +374,79 @@ def test_prefix_sum_and_flatnonzero_match_numpy(n):
             np.asarray(bloom.flatnonzero(jnp.asarray(ok), size)), want)
 
 
+@pytest.mark.parametrize("n,size,live", [
+    (1 << 12, 1 << 8, 100),             # size < n
+    (1 << 12, 1 << 12, 1000),           # size = n
+    (1 << 12, 64, 0),                   # no survivors
+    (1 << 12, 1 << 8, 1 << 8),          # survivors exactly fill size
+])
+def test_fused_compactions_match_numpy(n, size, live):
+    """The fused probe's survivor compaction into `size` slots, count
+    (mask positions) and gather (`idx` at them), on both device engines:
+    front-packed ascending positions with zero fill, as
+    `np.flatnonzero`."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(n + size + live)
+    ok = np.zeros(n, bool)
+    ok[rng.choice(n, live, replace=False)] = True
+    idx = rng.permutation(1 << 14)[:n].astype(np.int32)
+    want = np.zeros(size, np.int64)
+    want[:live] = np.flatnonzero(ok)
+    for backend in ("jax", "pallas"):
+        eng = get_engine(backend, device_resident=True)
+        np.testing.assert_array_equal(
+            np.asarray(eng.fused_compact(jnp.asarray(ok), None, size)),
+            want)
+        np.testing.assert_array_equal(
+            np.asarray(eng.fused_compact(jnp.asarray(ok),
+                                         jnp.asarray(idx), size)),
+            idx[want])
+
+
+@pytest.mark.parametrize("backend", ["jax", "pallas"])
+@pytest.mark.parametrize("start", ["all", "some"])
+@pytest.mark.parametrize("case", ["shrinks", "stays", "removes_nothing"])
+def test_fused_probe_compacts_into_survivor_bucket(backend, start, case):
+    """A device-resident fused probe (count variant from an all-live
+    start, gather variant from a partial one) vs the host mirror: the
+    same survivor ids and per-filter live counts whether the survivors'
+    bucket shrinks, stays, or no row is removed; the survivor ids fill
+    their own bucket, and a probe that removes nothing dispatches no
+    compaction."""
+    n = 3000
+    rng = np.random.default_rng(15)
+    keys = rng.permutation(n).astype(np.int64)          # distinct
+    kept = {"shrinks": 100, "stays": 2700, "removes_nothing": n}[case]
+    mask = np.ones(n, bool) if start == "all" else rng.random(n) < 0.97
+    host = get_engine("numpy")
+    words = np.asarray(host.build_filter(
+        host.keys(np.arange(kept, dtype=np.int64))).words)
+    every = np.asarray(host.build_filter(host.keys(keys)).words)
+    outs = {}
+    for b in ("numpy", backend):
+        eng = host if b == "numpy" else get_engine(b, device_resident=True)
+        stats = device_plane.DeviceStats()
+        with device_plane.track(stats):
+            scan = eng.begin(mask)
+            scan.probe([(words, eng.keys(keys)), (every, eng.keys(keys))])
+        outs[b] = (np.flatnonzero(scan.mask), list(scan.live_after))
+    ids, live_after = outs[backend]
+    np.testing.assert_array_equal(ids, outs["numpy"][0])
+    assert live_after == outs["numpy"][1]
+    width = eng.bucket(int(mask.sum()))
+    if case == "removes_nothing":
+        assert stats.compact_calls == 0
+    else:
+        assert stats.compact_calls == 1
+        assert (eng.bucket(len(ids)) < width) == (case == "shrinks")
+        assert stats.compact_width == width
+        assert stats.compact_slots == eng.bucket(len(ids))
+        dev_ids = device_plane.to_host(scan._idx)
+        assert len(dev_ids) == eng.bucket(len(ids))
+        np.testing.assert_array_equal(dev_ids[:len(ids)], ids)
+    assert stats.compact_slots <= stats.compact_width
+
+
 def test_device_engine_empty_inputs_delegate():
     """The engine entry handles zero-length sides (the device kernel
     itself is only entered with rows on both sides)."""
@@ -459,7 +532,9 @@ def test_device_plane_cuts_round_trips(tpch_small):
             rep = stats.report()["device"]
             assert set(rep) == {"h2d_syncs", "h2d_bytes", "d2h_syncs",
                                 "d2h_bytes", "round_trips",
-                                "fused_calls"}
+                                "fused_calls", "compact_calls",
+                                "compact_slots", "compact_width"}
+            assert rep["compact_slots"] <= rep["compact_width"]
             tot[mode] += rep["round_trips"]
             digests[mode] = res
         _assert_tables_exact(digests["on"], digests["off"], qn)

@@ -93,16 +93,39 @@ def test_build_refuses_filters_over_vmem_budget(one_chip):
 
 def test_engine_fused_probes_compile(one_chip):
     """The device-resident plane's per-vertex graphs around the kernel:
-    cumulative masks, live counts, survivor compaction."""
+    cumulative masks and live counts, returning the last survivor mask
+    (compaction is a program of its own)."""
     n = SF1_LINEITEM
     words = (_spec(one_chip, (1 << 17, 8)), _spec(one_chip, (1 << 13, 8)))
     keys = (_spec(one_chip, (n,)),) * 2
     count = _spec(one_chip, (), jnp.int32)
-    _compile(eb._fused_pallas_count, words, keys, keys, count, k=4,
-             interpret=False)
-    _compile(eb._fused_pallas_gather, words, keys, keys,
-             _spec(one_chip, (1 << 20,), jnp.int32), count, k=4,
-             interpret=False)
+    for compiled, width in (
+            (_compile(eb._fused_pallas_count, words, keys, keys, count,
+                      k=4, interpret=False), n),
+            (_compile(eb._fused_pallas_gather, words, keys, keys,
+                      _spec(one_chip, (1 << 20,), jnp.int32), count, k=4,
+                      interpret=False), 1 << 20)):
+        assert "tpu_custom_call" in compiled.as_text()
+        ok, counts = compiled.out_info
+        assert (ok.shape, ok.dtype) == ((width,), jnp.bool_)
+        assert counts.shape == (len(words),)
+
+
+@pytest.mark.parametrize("variant,n,size", [
+    ("count", SF1_LINEITEM, 1 << 19),
+    ("gather", 1 << 22, 1 << 16),
+])
+def test_fused_compactions_compile(one_chip, variant, n, size):
+    """A fused probe's survivor compaction into the survivors' bucket:
+    lineitem's 2^23-row mask into 2^19 slots, a 2^22-row survivor set
+    into 2^16."""
+    ok = _spec(one_chip, (n,), jnp.bool_)
+    if variant == "count":
+        compiled = _compile(eb._fused_pallas_count_compact, ok, size=size)
+    else:
+        compiled = _compile(eb._fused_pallas_gather_compact, ok,
+                            _spec(one_chip, (n,), jnp.int32), size=size)
+    assert compiled.out_info.shape == (size,)
 
 
 def test_segment_join_compiles(one_chip):
