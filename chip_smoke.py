@@ -147,11 +147,14 @@ def kernel_checks(cat, seed: int) -> None:
                                         interpret=False))
     check(np.array_equal(single, ref[0]),
           "bloom probe_pallas differs from probe_np")
-    fused = np.asarray(kb.multi_probe_pallas(
-        tuple(words), (dev(o_lo),) * 2, (dev(o_hi),) * 2,
-        interpret=False))
-    check(np.array_equal(fused[0], ref[0])
-          and np.array_equal(fused[1], ref[0] & ref[1]),
+    both = ref[0] & ref[1]
+    fused, counts = kb.multi_probe_pallas(
+        tuple(words), (dev(o_lo),) * 2, (dev(o_hi),) * 2, len(okeys),
+        interpret=False)
+    want = both & (np.arange(len(both)) < len(okeys))
+    check(np.array_equal(np.asarray(fused), want)
+          and list(np.asarray(counts)) == [
+              int(ref[0][:len(okeys)].sum()), int(want.sum())],
           "bloom multi_probe_pallas differs from the ANDed probe_np")
     log(f"kernels bloom: build {len(lkeys)} + {len(okeys)} keys, probe "
         f"{len(okeys)} keys, equal to numpy: "

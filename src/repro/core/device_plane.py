@@ -65,6 +65,11 @@ class DeviceStats:
     compact_calls: int = 0
     compact_slots: int = 0
     compact_width: int = 0
+    # fused probes' walks over their live prefix: the tiles walked, the
+    # rows in them, and the probes' full widths
+    probe_tiles: int = 0
+    probe_rows_walked: int = 0
+    probe_width: int = 0
     # span name -> [count, nanoseconds] (`span`; `serve.queued` is set
     # by the server)
     spans: Dict[str, List[int]] = field(default_factory=dict)
@@ -89,6 +94,9 @@ class DeviceStats:
         self.compact_calls += other.compact_calls
         self.compact_slots += other.compact_slots
         self.compact_width += other.compact_width
+        self.probe_tiles += other.probe_tiles
+        self.probe_rows_walked += other.probe_rows_walked
+        self.probe_width += other.probe_width
         for name, (count, ns) in other.spans.items():
             self.add_span(name, ns, count)
 
@@ -103,6 +111,9 @@ class DeviceStats:
             "compact_calls": self.compact_calls,
             "compact_slots": self.compact_slots,
             "compact_width": self.compact_width,
+            "probe_tiles": self.probe_tiles,
+            "probe_rows_walked": self.probe_rows_walked,
+            "probe_width": self.probe_width,
         }
 
     def span_report(self) -> dict:
@@ -186,6 +197,14 @@ def count_compact(size: int, width: int) -> None:
         s.compact_calls += 1
         s.compact_slots += int(size)
         s.compact_width += int(width)
+
+
+def count_probe_walk(tiles: int, tile: int, width: int) -> None:
+    s = active()
+    if s is not None:
+        s.probe_tiles += int(tiles)
+        s.probe_rows_walked += int(tiles) * int(tile)
+        s.probe_width += int(width)
 
 
 def scalar(x) -> int:

@@ -427,24 +427,18 @@ def _fused_probe_gather(words, hs, g1s, g2s, idx, count, k):
     return _fused_and(words, hg, g1g, g2g, ok, k)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def _fused_pallas_count(words, los, his, count, k, interpret):
+@functools.partial(jax.jit, static_argnames=("k", "tile", "interpret"))
+def _fused_pallas_count(words, los, his, count, k, tile, interpret):
     from repro.kernels.bloom import bloom as _k
-    cum = _k.multi_probe_pallas(words, los, his, k=k, interpret=interpret)
-    n = los[0].shape[0]
-    cum = cum & (jnp.arange(n, dtype=jnp.int32) < count)[None, :]
-    return cum[-1], jnp.sum(cum, axis=1, dtype=jnp.int32)
+    return _k.multi_probe_pallas(words, los, his, count, k=k, tile=tile,
+                                 interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def _fused_pallas_gather(words, los, his, idx, count, k, interpret):
+@functools.partial(jax.jit, static_argnames=("k", "tile", "interpret"))
+def _fused_pallas_gather(words, los, his, idx, count, k, tile, interpret):
     from repro.kernels.bloom import bloom as _k
-    los = tuple(a[idx] for a in los)
-    his = tuple(a[idx] for a in his)
-    cum = _k.multi_probe_pallas(words, los, his, k=k, interpret=interpret)
-    n = idx.shape[0]
-    cum = cum & (jnp.arange(n, dtype=jnp.int32) < count)[None, :]
-    return cum[-1], jnp.sum(cum, axis=1, dtype=jnp.int32)
+    return _k.multi_probe_pallas(words, los, his, count, idx, k=k,
+                                 tile=tile, interpret=interpret)
 
 
 # `_compact` under the names of the Pallas probe programs it completes, so
@@ -811,12 +805,15 @@ class _DeviceScan(VertexScan):
             words_dev, [ek for _, ek in incoming], self._idx,
             self._count, self._n)
         device_plane.count_fused()
+        width = ok.shape[0]
+        tile = self._e.probe_tile(width)
+        device_plane.count_probe_walk(-(-self._count // tile), tile, width)
         # the vertex's ONE d2h sync
         host_counts = device_plane.to_host(dcounts)
         self.live_after = [int(c) for c in host_counts]
         # rows-probed accounting matches the sequential path: filter f
-        # "sees" the rows still live when it runs (the device does
-        # padded-width work regardless; stats stay comparable)
+        # "sees" the rows still live when it runs (the device walks the
+        # tiles up to the last live row, `probe_rows_walked`)
         rows = self._count + int(host_counts[:-1].sum())
         new_count = int(host_counts[-1])
         if new_count != self._count:
@@ -1013,6 +1010,13 @@ class BloomEngine:
         live-count-after-each-filter vector) — the caller syncs the
         counts once per vertex."""
         raise NotImplementedError
+
+    def probe_tile(self, width: int) -> int:
+        """Rows per step of `fused_probe_idx`'s walk over a `width`-row
+        probe; the walk stops after the tile that holds the last live
+        row. One tile of the whole width: the probe runs over all of
+        it."""
+        return width
 
     def fused_compact(self, ok, idx, size: int):
         """The survivor row ids of a `fused_probe_idx` mask (`idx` as
@@ -1214,9 +1218,14 @@ class PallasEngine(BloomEngine):
         los, his = zip(*(ek.dev(b) for ek in eks))
         if idx is None:
             return _fused_pallas_count(words, los, his, count, self.k,
-                                       self.interpret)
+                                       self.probe_tile(b), self.interpret)
         return _fused_pallas_gather(words, los, his, idx, count, self.k,
+                                    self.probe_tile(idx.shape[0]),
                                     self.interpret)
+
+    def probe_tile(self, width):
+        from repro.kernels.bloom import bloom as _k
+        return _k.walk_tile(width)
 
     def fused_compact(self, ok, idx, size):
         if idx is None:

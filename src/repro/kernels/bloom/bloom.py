@@ -10,16 +10,28 @@ Layout, as the v5e compiler accepts it:
 * **keys stream lane-dense.** A key column of n rows (n % TILE == 0) is
   viewed as (n/128, 128) and tiled in (rows, 128) blocks, rows a
   multiple of 8, over a 1-D grid.
-* **probe: filter in HBM, block rows fetched by XLA.** The vector unit
-  has no gather from VMEM, and an (nblocks, 8) filter kept in VMEM
-  would pad its 8 lanes to 128 (16× its size). So an XLA gather ahead
-  of the kernel fetches each key's block row from the HBM filter as 8
-  lane-dense word planes (`_probe_rows`; a row gather, because the TPU
-  compiler spends tens of seconds on the (nblocks, 8) -> flat relayout
-  a word gather needs at 2^15-2^17 blocks), and the kernel
-  hashes, picks the k probed words, tests their bits, and ANDs across
-  every filter of a vertex (the cumulative survivor mask after each
-  filter).
+* **probe: a walk over the live prefix in tiles.** A probe of n rows
+  of which the first `count` are live (the survivors, front-packed)
+  walks them in tiles of `walk_tile(n)` rows (at most `PROBE_TILE`) in
+  a `lax.fori_loop` whose trip count, ceil(count / tile), is computed
+  on the device from the traced `count`: tiles past the last live row
+  are never walked, and no shape depends on `count`. Each step slices
+  its tile's key halves (or gathers them at the tile's survivor ids),
+  hashes them, fetches their block rows, runs the Pallas kernel over
+  the tile, masks the rows past `count`, adds each filter's live count
+  and writes the tile's survivor mask into the (n,) output. So the
+  probe's working memory is one tile's, whatever the bucket.
+* **block rows fetched by XLA, per tile.** The vector unit has no
+  gather from VMEM, and an (nblocks, 8) filter kept in VMEM would pad
+  its 8 lanes to 128 (16x its size). So an XLA gather ahead of the
+  kernel fetches each of the tile's keys' block row from the HBM filter
+  as 8 lane-dense word planes (`_probe_rows`; a row gather, because the
+  TPU compiler spends tens of seconds on the (nblocks, 8) -> flat
+  relayout a word gather needs at 2^15-2^17 blocks; over a whole 2^25
+  bucket the (rows, 8) gather and its transpose took more HBM than the
+  chip has), and the kernel hashes, picks the k probed words, tests
+  their bits, and ANDs across every filter of a vertex (the cumulative
+  survivor mask of the tile after each filter).
 * **build: filter resident in VMEM, lane-dense.** The filter accumulates
   in its (nblocks/16, 128) view — 16 blocks per 128-lane row, no
   padding — for all grid steps. Each key's block index and packed bit
@@ -50,7 +62,12 @@ from repro.kernels import resolve_interpret
 TILE = 1024  # keys per build grid step; key counts are multiples of TILE
 _LANE = 128
 _BLOCKS_PER_ROW = _LANE // LANES      # 16 filter blocks per 128-lane row
-_MAX_ROWS = 64                        # probe tile: up to 64 x 128 keys
+_MAX_ROWS = 64                        # probe grid step: up to 64 x 128 keys
+#: rows per step of a probe's walk over its live prefix (`walk_tile`).
+#: Compiled for a v5e, a 4-filter probe's temp is 0.21 GiB at 2^18
+#: whatever the bucket (0.35 at 2^19, 0.63 at 2^20, 1.19 at 2^21), and
+#: a 2^25-row bucket takes at most 128 steps
+PROBE_TILE = 1 << 18
 #: largest filter the build kernel keeps resident in VMEM (v5e: 128 MiB)
 VMEM_FILTER_MAX = 64 << 20
 
@@ -68,6 +85,16 @@ def _fmix32(h):
     h = h * _C2
     h = h ^ (h >> 16)
     return h
+
+
+def walk_tile(n: int) -> int:
+    """Rows per step of a probe's walk over n rows: the largest power of
+    two up to `PROBE_TILE` dividing n (n % TILE == 0 keeps it >= TILE
+    while `PROBE_TILE` >= TILE)."""
+    t = PROBE_TILE
+    while n % t:
+        t //= 2
+    return t
 
 
 def _tile_rows(n: int) -> int:
@@ -114,18 +141,12 @@ def _probe_kernel(*refs, k: int, m: int):
         out_ref[f] = ok.astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def multi_probe_pallas(words_list, los, his, k: int = DEFAULT_K,
-                       interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Fused probe of m filters over m key columns of the same rows.
-
-    `words_list`/`los`/`his` are equal-length tuples; every lo/hi is
-    uint32 [n] with n % TILE == 0. Returns bool [m, n]: row f is the
-    cumulative survivor mask after filters 0..f — bit-identical to
-    probing the filters one by one and ANDing."""
+def _probe_tile(words_list, los, his, k: int, interpret: bool):
+    """The Pallas probe of m filters over one tile of t rows (every
+    lo/hi uint32 [t], t % TILE == 0): bool [m, t], row f the cumulative
+    survivor mask after filters 0..f."""
     m = len(words_list)
     n = los[0].shape[0]
-    assert n % TILE == 0
     rows = n // _LANE
     br = _tile_rows(n)
     args, in_specs = [], []
@@ -141,16 +162,64 @@ def multi_probe_pallas(words_list, los, his, k: int = DEFAULT_K,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((m, br, _LANE), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, rows, _LANE), jnp.int32),
-        interpret=resolve_interpret(interpret),
+        interpret=interpret,
     )(*args)
     return out.reshape(m, n) != 0
+
+
+@functools.partial(jax.jit, static_argnames=("k", "tile", "interpret"))
+def multi_probe_pallas(words_list, los, his, count, idx=None,
+                       k: int = DEFAULT_K, tile: Optional[int] = None,
+                       interpret: Optional[bool] = None
+                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Fused probe of m filters over the live prefix of one row set.
+
+    `words_list`/`los`/`his` are equal-length tuples of filters and the
+    uint32 key halves they probe. Row i is `los[f][i]` or, with `idx`
+    (int32 [n]), `los[f][idx[i]]`; rows i < `count` are live. The walk
+    takes ceil(count / tile) steps of `tile` rows (default
+    `walk_tile(n)`; a power of two >= TILE dividing n). Returns
+    (ok bool [n], counts int32 [m]): ok is the AND of every filter over
+    the live rows, False past `count`; counts[f] is the number of live
+    rows that pass filters 0..f — bit-identical to probing the filters
+    one by one over the whole width, ANDing, and masking to `count`."""
+    m = len(words_list)
+    n = (los[0] if idx is None else idx).shape[0]
+    t = walk_tile(n) if tile is None else tile
+    assert n % t == 0 and t % TILE == 0, (n, t)
+    interpret = resolve_interpret(interpret)
+    count = jnp.asarray(count, jnp.int32)
+    row = jnp.arange(t, dtype=jnp.int32)
+
+    def step(i, carry):
+        ok, counts = carry
+        start = i * t
+        if idx is None:
+            lo_t = tuple(jax.lax.dynamic_slice(a, (start,), (t,))
+                         for a in los)
+            hi_t = tuple(jax.lax.dynamic_slice(a, (start,), (t,))
+                         for a in his)
+        else:
+            ids = jax.lax.dynamic_slice(idx, (start,), (t,))
+            lo_t = tuple(a[ids] for a in los)
+            hi_t = tuple(a[ids] for a in his)
+        cum = _probe_tile(words_list, lo_t, hi_t, k, interpret)
+        cum = cum & (start + row < count)[None, :]
+        counts = counts + jnp.sum(cum, axis=1, dtype=jnp.int32)
+        return jax.lax.dynamic_update_slice(ok, cum[-1], (start,)), counts
+
+    trips = (count + (t - 1)) // t
+    return jax.lax.fori_loop(0, trips, step,
+                             (jnp.zeros(n, jnp.bool_),
+                              jnp.zeros(m, jnp.int32)))
 
 
 def probe_pallas(words: jnp.ndarray, lo: jnp.ndarray, hi: jnp.ndarray,
                  k: int = DEFAULT_K,
                  interpret: Optional[bool] = None) -> jnp.ndarray:
-    """words [nblocks, LANES] uint32; lo/hi uint32 [n] (n % TILE == 0)."""
-    return multi_probe_pallas((words,), (lo,), (hi,), k=k,
+    """words [nblocks, LANES] uint32; lo/hi uint32 [n] (n % TILE == 0),
+    every row live."""
+    return multi_probe_pallas((words,), (lo,), (hi,), lo.shape[0], k=k,
                               interpret=interpret)[0]
 
 

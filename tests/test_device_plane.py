@@ -533,8 +533,11 @@ def test_device_plane_cuts_round_trips(tpch_small):
             assert set(rep) == {"h2d_syncs", "h2d_bytes", "d2h_syncs",
                                 "d2h_bytes", "round_trips",
                                 "fused_calls", "compact_calls",
-                                "compact_slots", "compact_width"}
+                                "compact_slots", "compact_width",
+                                "probe_tiles", "probe_rows_walked",
+                                "probe_width"}
             assert rep["compact_slots"] <= rep["compact_width"]
+            assert rep["probe_rows_walked"] <= rep["probe_width"]
             tot[mode] += rep["round_trips"]
             digests[mode] = res
         _assert_tables_exact(digests["on"], digests["off"], qn)

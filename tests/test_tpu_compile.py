@@ -3,7 +3,9 @@
 No chip is attached: the TPU compiler is handed a `v5e:2x2` topology
 description and compiles, at TPC-H SF-1 shapes, every Pallas kernel of
 the transfer -> join path with `interpret=False`, plus the jitted pieces
-of the device segment join. A kernel the chip's compiler would refuse
+of the device segment join; at SF-3 shapes, the fused probe (whose
+compiled temp must stay under 1 GiB), the largest build and the segment
+join. A kernel the chip's compiler would refuse
 (block shapes, unsupported primitives, VMEM over budget) fails here. The
 topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
@@ -22,6 +24,9 @@ from repro.kernels.semijoin import semijoin as ks
 
 SF1_LINEITEM = 1 << 23          # 6.0M lineitem rows, bucketed
 SF1_ORDERS = 1 << 21            # 1.5M orders rows, bucketed
+SF3_LINEITEM = 1 << 25          # 18.0M lineitem rows, bucketed
+SF3_ORDERS = 1 << 23            # 4.5M orders rows, bucketed
+GiB = 1 << 30
 
 
 @pytest.fixture(scope="module")
@@ -60,8 +65,11 @@ def test_multi_probe_compiles(one_chip, nblocks, n):
     words = tuple(_spec(one_chip, (nb, 8)) for nb in nblocks)
     keys = tuple(_spec(one_chip, (n,)) for _ in nblocks)
     compiled = _compile(kb.multi_probe_pallas, words, keys, keys,
-                        k=4, interpret=False)
+                        _spec(one_chip, (), jnp.int32), k=4,
+                        interpret=False)
     assert "tpu_custom_call" in compiled.as_text()
+    ok, counts = compiled.out_info
+    assert (ok.shape, counts.shape) == ((n,), (len(nblocks),))
 
 
 def test_probe_compiles(one_chip):
@@ -91,24 +99,59 @@ def test_build_refuses_filters_over_vmem_budget(one_chip):
                  key, key, _spec(one_chip, (1 << 10,), jnp.bool_))
 
 
+def _fused_probe(one_chip, nblocks, n, nidx=None):
+    """A fused probe program of the device-resident plane over n-row key
+    columns, the count variant or (`nidx`) the gather variant over
+    `nidx` survivor ids, compiled at the walk's tile."""
+    words = tuple(_spec(one_chip, (nb, 8)) for nb in nblocks)
+    keys = (_spec(one_chip, (n,)),) * len(nblocks)
+    count = _spec(one_chip, (), jnp.int32)
+    if nidx is None:
+        return _compile(eb._fused_pallas_count, words, keys, keys, count,
+                        k=4, tile=kb.walk_tile(n), interpret=False)
+    return _compile(eb._fused_pallas_gather, words, keys, keys,
+                    _spec(one_chip, (nidx,), jnp.int32), count, k=4,
+                    tile=kb.walk_tile(nidx), interpret=False)
+
+
 def test_engine_fused_probes_compile(one_chip):
     """The device-resident plane's per-vertex graphs around the kernel:
-    cumulative masks and live counts, returning the last survivor mask
-    (compaction is a program of its own)."""
+    the walk over the live prefix, returning the survivor mask and live
+    counts (compaction is a program of its own)."""
     n = SF1_LINEITEM
-    words = (_spec(one_chip, (1 << 17, 8)), _spec(one_chip, (1 << 13, 8)))
-    keys = (_spec(one_chip, (n,)),) * 2
-    count = _spec(one_chip, (), jnp.int32)
+    nblocks = (1 << 17, 1 << 13)
     for compiled, width in (
-            (_compile(eb._fused_pallas_count, words, keys, keys, count,
-                      k=4, interpret=False), n),
-            (_compile(eb._fused_pallas_gather, words, keys, keys,
-                      _spec(one_chip, (1 << 20,), jnp.int32), count, k=4,
-                      interpret=False), 1 << 20)):
+            (_fused_probe(one_chip, nblocks, n), n),
+            (_fused_probe(one_chip, nblocks, n, 1 << 20), 1 << 20)):
         assert "tpu_custom_call" in compiled.as_text()
         ok, counts = compiled.out_info
         assert (ok.shape, ok.dtype) == ((width,), jnp.bool_)
-        assert counts.shape == (len(words),)
+        assert counts.shape == (len(nblocks),)
+
+
+# the parent's compiled temp of the SF-1 probe over the whole bucket, in
+# GiB, by filters: the walk must need no more
+SF1_WHOLE_WIDTH_TEMP = {1: 0.5, 2: 0.75, 4: 4.5}
+
+
+@pytest.mark.parametrize("m", sorted(SF1_WHOLE_WIDTH_TEMP))
+def test_fused_probe_temp_at_sf1(one_chip, m):
+    nblocks = (1 << 19, 1 << 17, 1 << 15, 1 << 13)[:m]
+    temp = _fused_probe(one_chip, nblocks, SF1_LINEITEM) \
+        .memory_analysis().temp_size_in_bytes
+    assert temp <= SF1_WHOLE_WIDTH_TEMP[m] * GiB
+
+
+@pytest.mark.parametrize("nidx", [None, 1 << 24])
+def test_fused_probe_fits_at_sf3(one_chip, nidx):
+    """Q9's and Q21's lineitem vertex at SF 3: 4 filters, the largest
+    2^21 blocks, over the 2^25-row bucket or 2^24 survivors of it. Over
+    the whole bucket the chip's compiler refused these programs (17 and
+    18 GB of 15.75 GB of HBM); the walk keeps a tile's working set."""
+    nblocks = (1 << 21, 1 << 19, 1 << 17, 1 << 13)
+    compiled = _fused_probe(one_chip, nblocks, SF3_LINEITEM, nidx)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < GiB
 
 
 @pytest.mark.parametrize("variant,n,size", [
@@ -142,6 +185,25 @@ def test_segment_join_compiles(one_chip):
     _compile(sj._segjoin_outcounts_left, col, count)
     _compile(sj._segjoin_emit, _spec(one_chip, (m,), i32), col, col, col,
              total_len=n, left=True)
+
+
+def test_sf3_build_and_segment_join_compile(one_chip):
+    """SF 3's largest Bloom build (2^25 keys into 2^21 blocks, the VMEM
+    budget exactly) and its orders build x lineitem probe segment
+    join."""
+    key = _spec(one_chip, (SF3_LINEITEM,))
+    compiled = _compile(
+        lambda lo, hi, m: kb.build_pallas(lo, hi, m, 1 << 21,
+                                          interpret=False),
+        key, key, _spec(one_chip, (SF3_LINEITEM,), jnp.bool_))
+    assert "tpu_custom_call" in compiled.as_text()
+    i32 = jnp.int32
+    n, m = SF3_LINEITEM, SF3_ORDERS
+    col = _spec(one_chip, (n,), i32)
+    _compile(sj._segjoin_counts, _spec(one_chip, (4, m)),
+             _spec(one_chip, (3, n)), _spec(one_chip, (), i32))
+    _compile(sj._segjoin_emit, _spec(one_chip, (m,), i32), col, col, col,
+             total_len=n, left=False)
 
 
 def test_semijoin_kernels_compile(one_chip):
