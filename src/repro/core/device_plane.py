@@ -70,6 +70,10 @@ class DeviceStats:
     probe_tiles: int = 0
     probe_rows_walked: int = 0
     probe_width: int = 0
+    # segment joins, and those whose build keys share one high half, so
+    # that their search compares one key plane
+    segjoin_calls: int = 0
+    segjoin_narrow: int = 0
     # span name -> [count, nanoseconds] (`span`; `serve.queued` is set
     # by the server)
     spans: Dict[str, List[int]] = field(default_factory=dict)
@@ -97,6 +101,8 @@ class DeviceStats:
         self.probe_tiles += other.probe_tiles
         self.probe_rows_walked += other.probe_rows_walked
         self.probe_width += other.probe_width
+        self.segjoin_calls += other.segjoin_calls
+        self.segjoin_narrow += other.segjoin_narrow
         for name, (count, ns) in other.spans.items():
             self.add_span(name, ns, count)
 
@@ -114,6 +120,8 @@ class DeviceStats:
             "probe_tiles": self.probe_tiles,
             "probe_rows_walked": self.probe_rows_walked,
             "probe_width": self.probe_width,
+            "segjoin_calls": self.segjoin_calls,
+            "segjoin_narrow": self.segjoin_narrow,
         }
 
     def span_report(self) -> dict:
@@ -205,6 +213,13 @@ def count_probe_walk(tiles: int, tile: int, width: int) -> None:
         s.probe_tiles += int(tiles)
         s.probe_rows_walked += int(tiles) * int(tile)
         s.probe_width += int(width)
+
+
+def count_segjoin(narrow: bool) -> None:
+    s = active()
+    if s is not None:
+        s.segjoin_calls += 1
+        s.segjoin_narrow += int(narrow)
 
 
 def scalar(x) -> int:

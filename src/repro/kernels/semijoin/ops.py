@@ -165,22 +165,28 @@ def joinmap_lookup(table, keys: np.ndarray, use_pallas: bool = True,
 
 # --------------------------------------------------------------------------
 # device sorted-segment join (the device-resident data plane, DESIGN.md
-# §15): duplicate-key joins on device — pair binary search of every probe
-# key into the sorted build side, segment emission — with the host
-# syncing one output-size scalar per join. Bit-identical (build_idx,
-# probe_idx) to `engine_join.sorted_join_indices`: signed int64 keys are
-# compared as (hi ^ sign, lo) unsigned pairs, and a leading invalid bit
-# sorts NULL-key and padding rows past every real key so they can never
-# match (NULL-key probe rows are handled by zeroing their match counts —
-# no compact-and-remap on either side).
+# §15): duplicate-key joins on device — one lower-bound binary search of
+# every probe key into the sorted live build rows, segment emission —
+# with the host syncing one output-size scalar per join. Bit-identical
+# (build_idx, probe_idx) to `engine_join.sorted_join_indices`: signed
+# int64 keys are compared as (hi ^ sign, lo) unsigned pairs.
 #
 # The build side's stable order is computed on host, where its keys
 # already are, and travels sorted in the build upload: XLA's TPU
 # compiler takes 10-45 s per shape for a single-key sort at 2^14-2^23
-# rows, and minutes for a three-key one. Everything past the sort —
-# searches, counts, selection, emission — runs on device, with
-# `bloom.prefix_sum` + binary search in place of scatters and cumsums
-# for the same reason.
+# rows, and minutes for a three-key one. The build stack is
+# (lo[, hi ^ sign], run, order) in that order, with `nb_live` the number
+# of live rows: NULL-key and padding rows sort after every live row, so
+# a search over [0, nb_live) never reaches them (NULL-key probe rows are
+# handled by zeroing their match counts — no compact-and-remap on either
+# side). `run` is each live row's remaining equal-key run length, so a
+# probe row's match count is `run` at its lower bound and no
+# right-bound search is needed. When every live build key has the same
+# high half — every single-column TPC-H key does — the hi plane is left
+# out and that half travels as a scalar: each search step then gathers
+# one plane. Everything past the sort — search, counts, selection,
+# emission — runs on device, with `bloom.prefix_sum` + binary search in
+# place of scatters and cumsums for the same reason.
 # --------------------------------------------------------------------------
 
 _SIGN = np.uint32(0x80000000)
@@ -198,53 +204,71 @@ def _pad_pow2(a: np.ndarray, m: int, fill=0) -> np.ndarray:
     return out
 
 
-def _search3(slo, shi, sinv, qlo, qhi, right: bool):
-    """searchsorted over (inv, hi, lo) triples for queries with inv=0,
-    as a log2(n)-step binary-search loop (no pair-valued searchsorted
-    primitive on device)."""
-    n = slo.shape[0]
+def _run_lengths(keys: np.ndarray) -> np.ndarray:
+    """For sorted `keys`: the end of each row's equal-key run less the
+    row's position."""
+    n = len(keys)
+    ends = np.append(np.flatnonzero(keys[1:] != keys[:-1]) + 1, n)
+    return np.repeat(ends, np.diff(ends, prepend=0)) - np.arange(n)
+
+
+def _lower_bound(keys, q, nb_live):
+    """First position in [0, nb_live) whose key is not below the query's
+    (nb_live where none is), over the key planes `keys` = (lo[, hi]) of
+    the sorted build rows, as a fixed log2(n)-step binary-search loop (no
+    pair-valued searchsorted primitive on device)."""
+    n = keys[0].shape[0]
+
+    def below(at):
+        lt = keys[0][at] < q[0]
+        for k, qk in zip(keys[1:], q[1:]):
+            m = k[at]
+            lt = (m < qk) | ((m == qk) & lt)
+        return lt
 
     def step(_, bounds):
         lo_b, hi_b = bounds
         mid = (lo_b + hi_b) >> 1
-        midc = jnp.minimum(mid, n - 1)
-        mlo, mhi, minv = slo[midc], shi[midc], sinv[midc]
-        if right:
-            lt = (mhi < qhi) | ((mhi == qhi) & (mlo <= qlo))
-        else:
-            lt = (mhi < qhi) | ((mhi == qhi) & (mlo < qlo))
         active = lo_b < hi_b
-        go = active & (minv == 0) & lt
+        go = active & below(jnp.minimum(mid, n - 1))
         return (jnp.where(go, mid + 1, lo_b),
                 jnp.where(active & ~go, mid, hi_b))
 
-    bounds = (jnp.zeros(qlo.shape, jnp.int32),
-              jnp.full(qlo.shape, n, jnp.int32))
+    bounds = (jnp.zeros(q[0].shape, jnp.int32),
+              jnp.full(q[0].shape, nb_live, jnp.int32))
     return jax.lax.fori_loop(0, max(1, int(n).bit_length()), step,
                              bounds)[0]
 
 
 @jax.jit
-def _segjoin_counts(bstack, pstack, np_live):
+def _segjoin_counts(bstack, pstack, np_live, nb_live, bhi):
     """(order, lo_pos, counts): build sort permutation, each probe row's
     first-match position in it, and its match count (0 past `np_live`).
 
     Both sides arrive as one stacked uint32 upload each — build planes
-    in sorted order (lo, hi_flipped, invalid, order), probe planes (lo,
+    in sorted order (lo[, hi_flipped], run, order), probe planes (lo,
     hi_flipped[, valid]) — so a join costs two h2d transfers however
-    many key planes it needs. A probe validity plane (shape-selected at
-    trace time) zeroes invalid rows' counts: inner drops them, left
-    emits them unmatched, anti keeps them, all in probe order with no
-    compact-and-remap."""
-    slo, shi, sinv = bstack[0], bstack[1], bstack[2]
-    plo, phi_f = pstack[0], pstack[1]
-    lo_pos = _search3(slo, shi, sinv, plo, phi_f, right=False)
-    hi_pos = _search3(slo, shi, sinv, plo, phi_f, right=True)
-    live = jnp.arange(plo.shape[0], dtype=jnp.int32) < np_live
+    many key planes it needs. A 3-plane build stack has no hi plane:
+    its live keys share the flipped high half `bhi`, and a probe row of
+    another high half is unmatched. A probe validity plane zeroes
+    invalid rows' counts: inner drops them, left emits them unmatched,
+    anti keeps them, all in probe order with no compact-and-remap. Both
+    plane counts are read off the stacks' shapes at trace time."""
+    wide = bstack.shape[0] == 4
+    keys = (bstack[0], bstack[1]) if wide else (bstack[0],)
+    q = (pstack[0], pstack[1]) if wide else (pstack[0],)
+    lo_pos = _lower_bound(keys, q, nb_live)
+    at = jnp.minimum(lo_pos, bstack.shape[1] - 1)
+    found = lo_pos < nb_live
+    for k, qk in zip(keys, q):
+        found = found & (k[at] == qk)
+    if not wide:
+        found = found & (pstack[1] == bhi)
+    live = jnp.arange(pstack.shape[1], dtype=jnp.int32) < np_live
     if pstack.shape[0] == 3:
         live = live & (pstack[2] != 0)
-    counts = jnp.where(live, hi_pos - lo_pos, 0)
-    return bstack[3].astype(jnp.int32), lo_pos, counts
+    counts = jnp.where(live & found, bstack[-2][at].astype(jnp.int32), 0)
+    return bstack[-1].astype(jnp.int32), lo_pos, counts
 
 
 @functools.partial(jax.jit, static_argnames=("want_zero",))
@@ -318,15 +342,17 @@ def segment_join_device(build_key: np.ndarray, probe_key: np.ndarray,
     keep = np.zeros(bb, bool)
     keep[live] = True
     dead = np.flatnonzero(~keep)
-    order = np.concatenate(
-        [live[np.argsort(build_key[live], kind="stable")], dead])
+    live = live[np.argsort(build_key[live], kind="stable")]
+    nl = len(live)
+    order = np.concatenate([live, dead])
     blo, bhi = hashing.key_halves(_pad_pow2(build_key, bb)[order])
-    bstack = np.empty((4, bb), np.uint32)
+    narrow = nl == 0 or bool((bhi[:nl] == bhi[0]).all())
+    bstack = np.zeros((3 if narrow else 4, bb), np.uint32)
     bstack[0] = blo
-    bstack[1] = bhi ^ _SIGN
-    bstack[2] = 0
-    bstack[2, len(live):] = 1
-    bstack[3] = order
+    if not narrow:
+        bstack[1] = bhi ^ _SIGN
+    bstack[-2, :nl] = _run_lengths(build_key[live])
+    bstack[-1] = order
     plo, phi = hashing.key_halves(_pad_pow2(probe_key, pb))
     pstack = np.empty((3 if probe_valid is not None else 2, pb),
                       np.uint32)
@@ -335,8 +361,10 @@ def segment_join_device(build_key: np.ndarray, probe_key: np.ndarray,
     if probe_valid is not None:
         pstack[2] = _pad_pow2(np.asarray(probe_valid, bool), pb, False)
 
-    order, lo_pos, counts = _segjoin_counts(dp.to_device(bstack),
-                                            dp.to_device(pstack), npr)
+    dp.count_segjoin(narrow)
+    order, lo_pos, counts = _segjoin_counts(
+        dp.to_device(bstack), dp.to_device(pstack), npr, nl,
+        bhi[0] ^ _SIGN)
 
     if how in ("semi", "anti"):
         sel, cnt = _segjoin_sel(counts, npr, how == "anti")

@@ -103,8 +103,10 @@ def test_device_stats_merge_and_report():
     with device_plane.track(a):
         device_plane.to_device(np.zeros(8, np.int64))
         device_plane.count_fused()
+        device_plane.count_segjoin(narrow=True)
     with device_plane.track(b):
         device_plane.to_host(device_plane.to_device(np.zeros(4, np.int64)))
+        device_plane.count_segjoin(narrow=False)
         with device_plane.span("transfer"):
             pass
     a.merge(b)
@@ -113,6 +115,7 @@ def test_device_stats_merge_and_report():
     assert rep["d2h_syncs"] == 1
     assert rep["round_trips"] == 3              # h2d + d2h
     assert rep["fused_calls"] == 1
+    assert (rep["segjoin_calls"], rep["segjoin_narrow"]) == (2, 1)
     assert "device_compactions" not in rep
     spans = a.span_report()
     assert spans["device.upload"][0] == 2       # one each, merged
@@ -355,6 +358,58 @@ def test_segment_join_device_heavy_duplicates(how):
     _check_segjoin(bk, pk, how, rng.random(700) < 0.9, None)
 
 
+def _key_plane_case(case):
+    """(build keys, probe keys, build validity, probe validity, whether
+    the live build keys share one high half) for one key layout."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    if case == "narrow":            # one column below 2^32, lo past 2^31
+        bk = rng.integers(0, 50, 300) * 80_000_000
+        pk = rng.integers(0, 60, 200) * 80_000_000
+        return bk, pk, None, None, True
+    if case == "wide":              # packed composite (a << 32) | b
+        bk = (rng.integers(0, 5, 300) << 32) | rng.integers(0, 6, 300)
+        pk = (rng.integers(0, 6, 200) << 32) | rng.integers(0, 7, 200)
+        return bk, pk, None, rng.random(200) < 0.8, False
+    if case == "uniform_hi":        # probes of another high half miss
+        bk = (7 << 32) | rng.integers(0, 20, 300)
+        pk = (rng.choice([7, 9], 200) << 32) | rng.integers(0, 20, 200)
+        return bk, pk, None, None, True
+    if case == "negative":          # high half 0xFFFFFFFF on every key
+        bk = -rng.integers(1, 40, 300)
+        neg = -rng.integers(1, 45, 200)
+        pk = np.where(rng.random(200) < 0.5, neg, neg & 0xFFFFFFFF)
+        return bk, pk, None, None, True
+    if case == "run_at_end":        # the largest key's run ends the live rows
+        bk = np.array([9, 1, 9, 3, 9, 20, 20, 9, 0])
+        bv = np.array([1, 1, 1, 1, 1, 0, 0, 1, 1], bool)
+        pk = np.array([9, 20, 1, 5, 9, 0, 21, -9])
+        return bk, pk, bv, None, True
+    if case == "null_tail":         # a live prefix, then NULL-key rows
+        bk = np.array([4, 4, 2, 8, 6, 6, 1, 3, 5, 7])
+        pk = np.arange(10)
+        return bk, pk, np.arange(10) < 6, None, True
+    assert case == "no_live"        # nb_live = 0
+    bk, pk = np.array([1, 2, 2]), np.array([2, 1, 0])
+    return bk, pk, np.zeros(3, bool), None, True
+
+
+@pytest.mark.parametrize("how", HOWS)
+@pytest.mark.parametrize("case", ["narrow", "wide", "uniform_hi",
+                                  "negative", "run_at_end", "null_tail",
+                                  "no_live"])
+def test_segment_join_device_key_planes(case, how):
+    """One lower-bound search over the live build rows, on one key plane
+    where the live build keys share their high half and on two where
+    they do not, against the NULL-contract reference; the counters say
+    which it searched."""
+    bk, pk, bv, pv, narrow = _key_plane_case(case)
+    stats = device_plane.DeviceStats()
+    with device_plane.track(stats):
+        _check_segjoin(bk.astype(np.int64), pk.astype(np.int64), how, bv,
+                       pv)
+    assert (stats.segjoin_calls, stats.segjoin_narrow) == (1, int(narrow))
+
+
 @pytest.mark.parametrize("n", [64, 1000, 1 << 14])
 def test_prefix_sum_and_flatnonzero_match_numpy(n):
     """The scatter- and cumsum-free device primitives vs numpy: exact
@@ -535,8 +590,11 @@ def test_device_plane_cuts_round_trips(tpch_small):
                                 "fused_calls", "compact_calls",
                                 "compact_slots", "compact_width",
                                 "probe_tiles", "probe_rows_walked",
-                                "probe_width"}
+                                "probe_width", "segjoin_calls",
+                                "segjoin_narrow"}
             assert rep["compact_slots"] <= rep["compact_width"]
+            assert rep["segjoin_narrow"] <= rep["segjoin_calls"]
+            assert (rep["segjoin_calls"] > 0) == (mode == "on")
             assert rep["probe_rows_walked"] <= rep["probe_width"]
             tot[mode] += rep["round_trips"]
             digests[mode] = res

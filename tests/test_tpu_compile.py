@@ -171,6 +171,16 @@ def test_fused_compactions_compile(one_chip, variant, n, size):
     assert compiled.out_info.shape == (size,)
 
 
+def _compile_segjoin_counts(sharding, m, n):
+    """The counts search over both build layouts: one key plane (lo,
+    run, order) and two (lo, hi, run, order)."""
+    count = _spec(sharding, (), jnp.int32)
+    for planes in (3, 4):
+        _compile(sj._segjoin_counts, _spec(sharding, (planes, m)),
+                 _spec(sharding, (3, n)), count, count,
+                 _spec(sharding, ()))
+
+
 def test_segment_join_compiles(one_chip):
     """`segment_join_device`'s jitted pieces, orders build x lineitem
     probe."""
@@ -178,8 +188,7 @@ def test_segment_join_compiles(one_chip):
     n, m = SF1_LINEITEM, SF1_ORDERS
     count = _spec(one_chip, (), i32)
     col = _spec(one_chip, (n,), i32)
-    _compile(sj._segjoin_counts, _spec(one_chip, (4, m)),
-             _spec(one_chip, (3, n)), count)
+    _compile_segjoin_counts(one_chip, m, n)
     _compile(sj._segjoin_sel, col, count, want_zero=True)
     _compile(sj._segjoin_total, col)
     _compile(sj._segjoin_outcounts_left, col, count)
@@ -200,8 +209,7 @@ def test_sf3_build_and_segment_join_compile(one_chip):
     i32 = jnp.int32
     n, m = SF3_LINEITEM, SF3_ORDERS
     col = _spec(one_chip, (n,), i32)
-    _compile(sj._segjoin_counts, _spec(one_chip, (4, m)),
-             _spec(one_chip, (3, n)), _spec(one_chip, (), i32))
+    _compile_segjoin_counts(one_chip, m, n)
     _compile(sj._segjoin_emit, _spec(one_chip, (m,), i32), col, col, col,
              total_len=n, left=False)
 
